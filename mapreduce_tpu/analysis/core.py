@@ -253,9 +253,9 @@ class AnalysisContext:
 
     @property
     def pallas_calls(self):
-        """``(infos, undigestable)`` — every pallas_call binding reachable
-        from the engine step/finish programs, digested once for the
-        vmem/kernel-race passes (:mod:`..pallas_info`)."""
+        """Every pallas_call binding reachable from the engine step/finish
+        programs, digested once for the vmem/kernel-race passes
+        (:mod:`..pallas_info`)."""
         if self._pallas_calls is None:
             from mapreduce_tpu.analysis import pallas_info
 

@@ -24,7 +24,7 @@ to be a stable, auditable bound, not a simulator:
   HBM total — they price interconnect, not local HBM.
 
 ``effective passes`` = HBM bytes / bytes-of-one-input-pass: how many times
-the program streams its own input, the unit the BENCHMARKS dead-end ledger
+the program streams its own input, the unit the round-5 dead-end notes
 prices in (the XLA sort measured at 2.6-3.4 such passes, round 6).
 """
 
@@ -42,7 +42,7 @@ _MATERIALIZING = {"sort", "gather", "scatter", "scatter-add", "scatter_add",
                   "slice", "pad", "transpose", "rev", "copy",
                   "pallas_call", "cumsum", "cumlogsumexp", "cummax",
                   "cummin", "cumprod", "associative_scan"}
-_CONTROL = {"pjit", "cond", "while", "scan", "shard_map", "custom_jvp_call",
+_CONTROL = {"jit", "cond", "while", "scan", "shard_map", "custom_jvp_call",
             "custom_vjp_call", "custom_vjp_call_jaxpr", "closed_call",
             "core_call", "xla_call", "remat", "checkpoint", "custom_partitioning"}
 # Sort comparators run log2(n) network stages over the comparator keys; the

@@ -57,8 +57,8 @@ GEOMETRY_SEARCH_VERSION = 1
 #: sort pricing and the PR-11 row arithmetic live.
 PRICING_CHUNK_BYTES = 1 << 25
 
-#: Measured worst-case window density (tools/density.py, BENCHMARKS.md
-#: round 4/11): 114 token ends in one 384-byte window on the Zipf bench
+#: Measured worst-case window density (tools/density.py, rounds 4 and
+#: 11): 114 token ends in one 384-byte window on the Zipf bench
 #: corpus (75 natural).  ceil(density * block_rows) > slots flags a
 #: candidate spill-RISKY — never rejected (the fallback is exact; the
 #: probe pass measures what the risk costs), but ranked with its eyes

@@ -8,10 +8,10 @@ grid), the scratch shapes, and the kernel body jaxpr itself.  This module
 builds that view once (:func:`collect_pallas_calls`) so the passes stay
 pure policy.
 
-Everything here reads public-enough jax internals (``GridMapping`` /
-``BlockMapping`` from ``jax._src.pallas.core``) *defensively*: a missing
-attribute degrades to ``None``/unknown and the passes downgrade their
-findings accordingly, rather than crashing the pipeline on a jax bump.
+Everything here reads jax's ``GridMapping`` / ``BlockMapping``
+(``jax._src.pallas.core``) as the installed jax (pinned in pyproject.toml)
+lays them out.  A field that is missing raises: a jax bump that moves one
+must fail the analysis loudly, never certify a kernel it could not read.
 """
 
 from __future__ import annotations
@@ -90,25 +90,18 @@ class PallasCallInfo:
 
 
 def _memory_space_of(aval) -> str:
-    ms = getattr(aval, "memory_space", None)
-    if ms is None:
-        return "?"
-    s = str(ms).lower()
+    """'vmem' | 'smem' | 'sem' | 'any' of a kernel ref's abstract value."""
+    s = str(aval.memory_space).lower()
     for known in ("vmem", "smem", "sem", "any"):
         if known in s:
             return known
-    return s or "?"
+    return s
 
 
-def _eval_index_map(bm, idx: tuple) -> Optional[tuple]:
-    imj = getattr(bm, "index_map_jaxpr", None)
-    if imj is None:
-        return None
-    try:
-        out = jax.core.eval_jaxpr(imj.jaxpr, imj.consts, *idx)
-        return tuple(int(x) for x in out)
-    except Exception:
-        return None
+def _eval_index_map(bm, idx: tuple) -> tuple:
+    imj = bm.index_map_jaxpr
+    out = jax.core.eval_jaxpr(imj.jaxpr, imj.consts, *idx)
+    return tuple(int(x) for x in out)
 
 
 def _revisited(bm, grid: tuple) -> Optional[bool]:
@@ -125,8 +118,6 @@ def _revisited(bm, grid: tuple) -> Optional[bool]:
     idx = [0] * len(dims)
     for _ in range(points):
         block = _eval_index_map(bm, tuple(idx))
-        if block is None:
-            return None
         if block in seen:
             return True
         seen.add(block)
@@ -138,66 +129,42 @@ def _revisited(bm, grid: tuple) -> Optional[bool]:
     return False
 
 
-def _kernel_invars(kernel_jaxpr) -> list:
-    j = getattr(kernel_jaxpr, "jaxpr", kernel_jaxpr)
-    return list(j.invars)
-
-
 def digest_eqn(eqn, program: str, enclosing_has_cond: bool
-               ) -> Optional[PallasCallInfo]:
-    """Build a PallasCallInfo from one pallas_call equation (None when the
-    params cannot be read — the caller reports that as an INFO finding)."""
+               ) -> PallasCallInfo:
+    """Build a PallasCallInfo from one pallas_call equation."""
     params = eqn.params
-    gm = params.get("grid_mapping")
-    kj = params.get("jaxpr")
-    if gm is None or kj is None:
-        return None
-    name_info = str(params.get("name_and_src_info", "") or "")
-    kernel_name = name_info.split(" at ")[0].strip() or "<pallas-kernel>"
-    grid = tuple(getattr(gm, "grid", ()) or ())
+    gm = params["grid_mapping"]
+    kj = params["jaxpr"]
+    src = kj.debug_info.func_src_info
+    kernel_name = kj.debug_info.func_name
+    grid = tuple(gm.grid)
 
-    n_in = int(getattr(gm, "num_inputs", 0))
-    mappings = list(getattr(gm, "block_mappings", ()) or ())
+    n_in = gm.num_inputs
     refs: list[RefInfo] = []
-    for i, bm in enumerate(mappings):
+    for i, bm in enumerate(gm.block_mappings):
         role = "in" if i < n_in else "out"
-        aval = getattr(bm, "block_aval", None)
-        inner = getattr(aval, "inner_aval", aval)
-        shape = tuple(getattr(bm, "block_shape", ()) or
-                      getattr(inner, "shape", ()))
-        dtype = np.dtype(getattr(inner, "dtype", np.uint8))
-        full = getattr(bm, "array_shape_dtype", None)
         refs.append(RefInfo(
             role=role, index=i if role == "in" else i - n_in,
-            block_shape=shape, dtype=dtype,
-            memory_space=_memory_space_of(aval),
-            array_shape=tuple(full.shape) if full is not None else None,
+            block_shape=tuple(bm.block_aval.inner_aval.shape),
+            dtype=np.dtype(bm.block_aval.inner_aval.dtype),
+            memory_space=_memory_space_of(bm.block_aval),
+            array_shape=tuple(bm.array_aval.shape),
             revisited=_revisited(bm, grid)))
-    invars = _kernel_invars(kj)
-    # Kernel invars trail with the scratch operands.
-    n_scratch = int(getattr(gm, "num_scratch_operands", 0))
-    for s, v in enumerate(invars[len(invars) - n_scratch:] if n_scratch
-                          else []):
-        aval = v.aval
-        inner = getattr(aval, "inner_aval", aval)
+    for s, aval in enumerate(gm.scratch_avals):
         refs.append(RefInfo(
             role="scratch", index=s,
-            block_shape=tuple(getattr(inner, "shape", ())),
-            dtype=np.dtype(getattr(inner, "dtype", np.uint8)),
+            block_shape=tuple(aval.inner_aval.shape),
+            dtype=np.dtype(aval.inner_aval.dtype),
             memory_space=_memory_space_of(aval),
             array_shape=None,
             revisited=True))  # scratch persists across grid iterations
 
-    cp = params.get("compiler_params") or {}
-    mosaic = cp.get("mosaic", {}) if isinstance(cp, dict) else {}
-    if not isinstance(mosaic, dict):  # newer jax: a params dataclass
-        mosaic = {k: getattr(mosaic, k, None)
-                  for k in ("vmem_limit_bytes", "dimension_semantics")}
+    mosaic = params["compiler_params"].get("mosaic_tpu")
     return PallasCallInfo(
-        kernel_name=kernel_name, src=name_info, program=program,
+        kernel_name=kernel_name, src=src, program=program,
         grid=grid, refs=tuple(refs), kernel_jaxpr=kj,
-        vmem_limit_bytes=mosaic.get("vmem_limit_bytes"),
-        dimension_semantics=mosaic.get("dimension_semantics"),
+        vmem_limit_bytes=mosaic.vmem_limit_bytes if mosaic else None,
+        dimension_semantics=mosaic.dimension_semantics if mosaic else None,
         enclosing_has_cond=enclosing_has_cond)
 
 
@@ -217,14 +184,10 @@ def _has_cond_outside_kernels(jaxpr) -> bool:
     return False
 
 
-def collect_pallas_calls(traces: dict) -> tuple[list, list]:
+def collect_pallas_calls(traces: dict) -> list[PallasCallInfo]:
     """Digest every pallas_call reachable from ``{program: ClosedJaxpr |
-    TraceFailure}``.  Returns ``(infos, undigestable)`` where undigestable
-    is ``[(program, src_string)]`` for equations whose params could not be
-    read (jax drift) — the passes surface those instead of silently
-    certifying nothing."""
+    TraceFailure}``, each distinct binding once."""
     infos: list[PallasCallInfo] = []
-    bad: list[tuple[str, str]] = []
     seen: set = set()
     for program, traced in traces.items():
         if isinstance(traced, trace.TraceFailure):
@@ -234,16 +197,12 @@ def collect_pallas_calls(traces: dict) -> tuple[list, list]:
             if eqn.primitive.name != "pallas_call":
                 continue
             info = digest_eqn(eqn, program, has_cond)
-            if info is None:
-                bad.append((program,
-                            str(eqn.params.get("name_and_src_info", "?"))))
-                continue
             sig = info.signature()
             if sig in seen:
                 continue
             seen.add(sig)
             infos.append(info)
-    return infos, bad
+    return infos
 
 
 # -- kernel-body ref event analysis (for the race lint) ----------------------
@@ -270,7 +229,7 @@ def ref_events(kernel_jaxpr) -> dict[int, list[RefEvent]]:
     scopes (``pl.when`` lowers to ``cond``) mark their events guarded;
     refs closed over into branch/body jaxprs are followed through the
     equation's invars (branch invars map 1:1 onto ``eqn.invars[1:]`` for
-    cond, onto ``eqn.invars`` for pjit-style calls).
+    cond, onto ``eqn.invars`` for jit-style calls).
     """
     j = getattr(kernel_jaxpr, "jaxpr", kernel_jaxpr)
     root_refs = {v: i for i, v in enumerate(j.invars)}
@@ -306,7 +265,7 @@ def ref_events(kernel_jaxpr) -> dict[int, list[RefEvent]]:
             sub_guarded = guarded or name == "cond"
             # Map refs that flow into the sub-jaxpr: cond passes operands
             # [pred, *args] with branch invars = args; call-like primitives
-            # (pjit, scan, while) pass operands 1:1 (scan/while carry
+            # (jit, scan, while) pass operands 1:1 (scan/while carry
             # prefixes don't matter here — only ref-typed vars can match).
             operands = eqn.invars[1:] if name == "cond" else eqn.invars
             for sub in subs:

@@ -37,6 +37,8 @@ from __future__ import annotations
 import json
 import os
 
+import jax.extend.core as jex_core
+
 from mapreduce_tpu.analysis import core, costmodel, meshcost, trace
 from mapreduce_tpu.analysis.passes.cost import (REGRESSION_TOLERANCE,
                                                 _BASELINES_DIR)
@@ -70,7 +72,7 @@ def load_collective_baseline(model: str, baselines_dir: str | None = None):
 
 
 def _is_literal(v) -> bool:
-    return hasattr(v, "val")  # jax.core.Literal carries .val; Var does not
+    return isinstance(v, jex_core.Literal)
 
 
 def _unwrap(jaxpr):
@@ -215,7 +217,7 @@ class CollectivePass:
                     ss, sb = self._price_walk(sub, times * length, mesh_spec,
                                               levels, entries, unpriced)
                     s, b = s + ss, b + sb
-            else:  # pjit / while / shard_map / custom calls: once through
+            else:  # jit / while / shard_map / custom calls: once through
                 s = b = 0
                 for sub in subs:
                     ss, sb = self._price_walk(sub, times, mesh_spec, levels,

@@ -6,7 +6,7 @@ Three jobs, all CI-gateable:
    FLOPs, and family shares from the traced step/finish jaxprs
    (:mod:`..costmodel`), plus ``effective input passes`` — how many times
    the step program streams its own chunk through HBM.  The unit the
-   BENCHMARKS dead-end ledger prices in, now computed by machine.
+   round-5 dead-end notes prices in, now computed by machine.
 
 2. **Sort-pricing cross-check**: the round-6 ledger's central measured
    claim — the XLA aggregation sort runs at **2.6-3.4 effective HBM
@@ -332,7 +332,7 @@ class CostPass:
                          f"(tolerance {tol:.0%}): the round-6 pricing no "
                          "longer holds"),
                 location=sort.location,
-                hint="re-measure on chip (opshare + BENCHMARKS round 6 "
+                hint="re-measure on chip (opshare + round-6 chip notes "
                      "discipline) and update "
                      "analysis/baselines/measured_rates.json deliberately")]
         return [core.Finding(
@@ -389,7 +389,7 @@ class CostPass:
         if split.get("traced_chunk_bytes") != report["traced_chunk_bytes"]:
             # Do NOT publish a gap: bench._cost_record copies the artifact
             # verbatim, and a passes_saved the gate just declared
-            # incomparable must not reach BENCH JSON / benchwatch rows.
+            # incomparable must not reach BENCH JSON.
             # A baseline MISSING the field is incomparable too — a wildcard
             # match would wave through a different-geometry pricing.
             return [core.Finding(
@@ -421,7 +421,7 @@ class CostPass:
                          "stopped deleting traffic"),
                 hint="the token-plane round-trip crept back in (or the "
                      "split baseline is stale); fix the kernel path or "
-                     "re-measure deliberately, BENCHMARKS.md discipline")]
+                     "re-measure deliberately and record it in PERF.md")]
         return [core.Finding(
             severity=core.INFO, pass_id=self.pass_id, model=ctx.model,
             hook="step",
@@ -500,7 +500,7 @@ class CostPass:
                          "stopped deleting sort traffic"),
                 hint="the taller-window arithmetic broke (geometry drift?) "
                      "or the off baseline is stale; fix or re-measure "
-                     "deliberately, BENCHMARKS.md discipline")]
+                     "deliberately and record it in PERF.md")]
         return [core.Finding(
             severity=core.INFO, pass_id=self.pass_id, model=ctx.model,
             hook="step",
@@ -555,7 +555,7 @@ class CostPass:
                          f"(gate: {REGRESSION_TOLERANCE:.0%})"),
                 hint="either fix the regression or regenerate baselines "
                      "deliberately (--write-baselines) with the pricing "
-                     "note in BENCHMARKS.md")]
+                     "note in PERF.md")]
         if growth < -REGRESSION_TOLERANCE:
             return [core.Finding(
                 severity=core.WARNING, pass_id=self.pass_id,

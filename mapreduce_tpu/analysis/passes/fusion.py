@@ -9,9 +9,9 @@ one ``pallas_call``).  This pass finds the NEXT such seams mechanically:
 
 * walk each traced program scope by scope (control bodies are their own
   scopes — a cond branch cannot fuse with its sibling), INLINING
-  transparent call boundaries: ``pjit``/``closed_call``/``remat``/
+  transparent call boundaries: ``jit``/``closed_call``/``remat``/
   ``shard_map`` wrappers are function-call plumbing XLA inlines (every
-  ``jnp.sort``/``jnp.cumsum`` arrives wrapped in its own one-eqn ``pjit``),
+  ``jnp.sort``/``jnp.cumsum`` arrives wrapped in its own one-eqn ``jit``),
   so their bodies continue the enclosing scope with invar/outvar identity
   threaded through — without this, no cross-library-call adjacency is
   visible at all;
@@ -42,6 +42,8 @@ certifies each one actually landed.
 
 from __future__ import annotations
 
+import jax.extend.core as jex_core
+
 from mapreduce_tpu.analysis import core, costmodel, trace
 from mapreduce_tpu.ops.pallas import meta
 
@@ -57,8 +59,7 @@ def _family(eqn) -> str:
 def _invar_vars(eqn) -> list:
     """The eqn's Var operands (Literals are unhashable constants — they
     carry no producer, so they can never witness adjacency)."""
-    return [v for v in eqn.invars
-            if hasattr(v, "aval") and hasattr(v, "count")]
+    return [v for v in eqn.invars if isinstance(v, jex_core.Var)]
 
 
 def _is_control(eqn) -> bool:
@@ -69,9 +70,9 @@ def _is_control(eqn) -> bool:
 
 # Call-shaped wrappers whose body is semantically inline in the enclosing
 # scope (XLA inlines them; crucially every jax.numpy library call — sort,
-# cumsum, ... — arrives as its own one-eqn pjit).  cond/while/scan stay
+# cumsum, ... — arrives as its own one-eqn jit).  cond/while/scan stay
 # fresh scopes: their bodies run zero/N times or per-branch.
-_TRANSPARENT = {"pjit", "closed_call", "core_call", "xla_call", "remat",
+_TRANSPARENT = {"jit", "closed_call", "core_call", "xla_call", "remat",
                 "checkpoint", "custom_jvp_call", "custom_vjp_call",
                 "custom_vjp_call_jaxpr", "custom_partitioning", "shard_map"}
 
@@ -158,11 +159,11 @@ def _scan_scope(eqns, acc: _Scan, values: _Values, state: list) -> None:
             if len(j.invars) == len(eqn.invars) \
                     and len(j.outvars) == len(eqn.outvars):
                 for inner, outer in zip(j.invars, eqn.invars):
-                    if hasattr(outer, "count"):  # Var (Literals carry none)
+                    if isinstance(outer, jex_core.Var):
                         values.alias(inner, outer)
                 _scan_scope(j.eqns, acc, values, state)
                 for outer, inner in zip(eqn.outvars, j.outvars):
-                    if hasattr(inner, "count"):
+                    if isinstance(inner, jex_core.Var):
                         values.alias(outer, inner)
                 continue
         ids = {values.of(v) for v in _invar_vars(eqn)}

@@ -1,7 +1,7 @@
 """Pass: host-sync & recompile-hazard detector.
 
 The streamed-ingest bench spent 66 s of a 97 s run inside ``dispatch``
-(BENCH_r05) — the classic smell of a hot loop that re-enters Python, blocks
+(round 5) — the classic smell of a hot loop that re-enters Python, blocks
 on the host, or recompiles.  The worst offenders are *visible statically*
 in the step program's jaxpr:
 

@@ -66,8 +66,7 @@ class KernelRacePass:
 
     def run(self, ctx: core.AnalysisContext) -> list[core.Finding]:
         out: list[core.Finding] = []
-        infos, _ = ctx.pallas_calls  # undigested reported by vmem pass
-        for info in infos:
+        for info in ctx.pallas_calls:
             out.extend(self._kernel_findings(ctx, info))
         return out
 

@@ -84,16 +84,8 @@ class VmemPass:
 
     def run(self, ctx: core.AnalysisContext) -> list[core.Finding]:
         out: list[core.Finding] = []
-        infos, undigested = ctx.pallas_calls
-        for program, src in undigested:
-            out.append(core.Finding(
-                severity=core.WARNING, pass_id=self.pass_id,
-                model=ctx.model, hook=program,
-                message=f"pallas_call params unreadable for {src!r} "
-                        "(jax internals drift?) — kernel NOT certified",
-                hint="update analysis/pallas_info.py for this jax version"))
         kernels = []
-        for info in infos:
+        for info in ctx.pallas_calls:
             vmem, smem = _footprint(info)
             out.extend(_budget_findings(
                 self.pass_id, ctx.model, info.program, info.kernel_name,

@@ -21,10 +21,11 @@ import dataclasses
 from typing import Any, Iterator
 
 import jax
+import jax.extend.core
 import numpy as np
 
-_ClosedJaxpr = jax.core.ClosedJaxpr
-_Jaxpr = jax.core.Jaxpr
+_ClosedJaxpr = jax.extend.core.ClosedJaxpr
+_Jaxpr = jax.extend.core.Jaxpr
 
 
 @dataclasses.dataclass(frozen=True)
@@ -212,7 +213,7 @@ def random_text(rng: np.random.Generator, n_bytes: int) -> np.ndarray:
 
 
 def eqn_subjaxprs(eqn) -> list:
-    """Every ClosedJaxpr/Jaxpr nested in an equation's params (pjit bodies,
+    """Every ClosedJaxpr/Jaxpr nested in an equation's params (jit bodies,
     cond branches, scan/while bodies, shard_map bodies, custom calls)."""
     out = []
     for v in eqn.params.values():

@@ -5,12 +5,14 @@ The reference's runtime is native C++/CUDA (host pipeline + launch wrappers,
 The library is compiled on first use from the bundled source (g++ is part of
 the toolchain; there is no pybind11 in the image, so the ABI is plain C via
 ctypes).  Every native entry point has a pure-Python fallback — absence of a
-compiler degrades performance, never correctness.
+compiler degrades performance, never correctness, and is logged as a
+warning.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,10 +20,10 @@ import threading
 import numpy as np
 
 from mapreduce_tpu import constants
+from mapreduce_tpu.runtime.logging import get_logger
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "chunker.cpp")
-_LIB = os.path.join(_DIR, "_chunker.so")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -32,45 +34,62 @@ for _b in constants.SEPARATOR_BYTES:
     SEP_LUT[_b] = 1
 
 
-def _build() -> bool:
-    # Compile to a private temp path and rename into place: an interrupted or
-    # concurrent build must never leave a partial .so at the load path (a
-    # truncated file with a fresh mtime would permanently disable the native
-    # path for every later process).
-    tmp = f"{_LIB}.{os.getpid()}.tmp"
+def lib_path() -> str:
+    """Where the library built from the current ``chunker.cpp`` lives: the
+    name carries a hash of the source, so a copied tree never loads a
+    library built from another version of it."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"_chunker-{digest}.so")
+
+
+def _build(lib: str) -> str | None:
+    """Compile ``lib``; returns the compiler's complaint on failure.
+
+    Compiles to a private temp path and renames into place: an interrupted
+    or concurrent build never leaves a partial library at the load path."""
+    tmp = f"{lib}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
-        if proc.returncode == 0 and os.path.exists(tmp):
-            os.replace(tmp, _LIB)
-            return True
-        return False
-    except (OSError, subprocess.TimeoutExpired):
-        return False
+        if proc.returncode != 0:
+            return proc.stderr.strip()[-2000:] or f"g++ rc={proc.returncode}"
+        os.replace(tmp, lib)
+        return None
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return repr(e)
     finally:
         if os.path.exists(tmp):
-            try:
-                os.unlink(tmp)
-            except OSError:
-                pass
+            os.unlink(tmp)
+
+
+def _unavailable(why: str) -> None:
+    get_logger("mapreduce_tpu.native").warning(
+        "native chunker unavailable, falling back to the slow Python "
+        "chunker: %s", why)
 
 
 def load() -> ctypes.CDLL | None:
-    """The chunker library, building it on first call; None if unavailable."""
+    """The chunker library, building it on first call; None if unavailable
+    (logged as a warning, once per process)."""
     global _lib, _tried
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
         if os.environ.get("MAPREDUCE_TPU_NO_NATIVE"):
+            _unavailable("MAPREDUCE_TPU_NO_NATIVE is set")
             return None
-        src_newer = (not os.path.exists(_LIB)
-                     or os.path.getmtime(_SRC) > os.path.getmtime(_LIB))
-        if src_newer and not _build():
-            return None
+        path = lib_path()
+        if not os.path.exists(path):
+            err = _build(path)
+            if err is not None:
+                _unavailable(f"build of {_SRC} failed: {err}")
+                return None
         try:
-            lib = ctypes.CDLL(_LIB)
-        except OSError:
+            lib = ctypes.CDLL(path)
+        except OSError as e:
+            _unavailable(f"cannot load {path}: {e}")
             return None
         u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
         i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")

@@ -1,8 +1,7 @@
 """Flight recorder: a bounded ring of recent events, dumped on failure.
 
-Bench rounds 1-2 lost their perf record to an opaque relay wedge — the
-process died (or was abandoned) with nothing on disk saying what it was
-doing.  The flight recorder fixes the general case (ISSUE 2 tentpole (3)):
+A run that dies or hangs must leave something on disk saying what it was
+doing.  The flight recorder does that (ISSUE 2 tentpole (3)):
 the executor records a tiny host-side event per dispatch / retry /
 checkpoint into a fixed-size ring buffer, and the failure path dumps the
 ring plus a state snapshot summary and the metrics-registry snapshot to a

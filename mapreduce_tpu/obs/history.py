@@ -675,7 +675,7 @@ def classify_drift(rows: List[dict]) -> dict:
     ==============  ========================================================
 
     Every flag carries the measured numbers, so downstream readers
-    (benchwatch rows, the serving layer) read arithmetic, not
+    (bench rows, the serving layer) read arithmetic, not
     adjectives."""
     rows = sorted(rows, key=_row_order)
     flags: List[dict] = []
@@ -745,8 +745,7 @@ def _median(xs: List) -> Optional[float]:
 
 
 def drift_report(index: dict) -> dict:
-    """Every drift group's verdict — the benchwatch ``history-report``
-    payload."""
+    """Every drift group's verdict — the ``history-report`` payload."""
     groups = sorted({r.get("group") for r in index.get("runs", {}).values()
                      if r.get("group")})
     return {g: classify_drift(group_rows(index, g)) for g in groups}

@@ -2,8 +2,8 @@
 
 One machine-readable record per streaming step/superstep, plus run start/end
 and checkpoint/retry/failure markers — the durable trace that makes a run's
-seconds attributable after the fact (the 3x streamed-vs-H2D gap of VERDICT
-r4 was unattributable until phase timers were threaded in by hand; the
+seconds attributable after the fact (the round-4 3x streamed-vs-H2D gap
+was unattributable until phase timers were threaded in by hand; the
 ledger records what those timers see, every run).
 
 Format: one JSON object per line, append-only, flushed per record so a

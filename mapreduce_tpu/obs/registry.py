@@ -38,7 +38,7 @@ import threading
 from typing import Dict, Iterable, Optional, Tuple
 
 # Log-spaced seconds buckets: 1 ms granularity at the bottom (a single fast
-# dispatch), a minute at the top (a wedged-relay compile).  Upper bounds,
+# dispatch), a minute at the top (a cold multi-minute compile).  Upper bounds,
 # inclusive; observations past the last bound land in +Inf.
 DEFAULT_BUCKETS: Tuple[float, ...] = (
     0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,

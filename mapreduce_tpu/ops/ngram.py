@@ -31,7 +31,7 @@ cheap sort:
     design instead fell back to the whole-chunk XLA scan via ``lax.cond``
     — but both cond branches are always compiled, so every n-gram program
     embedded the associative-scan formulation that compiles pathologically
-    slowly at production chunk sizes (VERDICT r2 #4); the poison rows
+    slowly at production chunk sizes (round-2 review #4); the poison rows
     delete that branch entirely.
 
 Hashing replicates :func:`...ops.tokenize._extend_grams` exactly (same

@@ -5,7 +5,7 @@ aggregation sort at 37-47 ms of a 72.8 ms chunk program (up to 65% of
 device time) on the 11.2M-row (key_hi, key_lo, packed) stream.  This module
 is the priced falsifying prototype for the one lever that analysis left
 open — replacing that sort with a digit-wise radix partition — built AFTER
-the pricing note (BENCHMARKS.md round 6) concluded it loses ~2-3x from
+the pricing note (round-6 chip notes) concluded it loses ~2-3x from
 measured rates.  It ships behind ``Config.sort_impl`` so an on-chip A/B can
 falsify the arithmetic instead of trusting it.
 
@@ -246,9 +246,9 @@ def radix_sort3(key_hi: jax.Array, key_lo: jax.Array, packed: jax.Array, *,
             f"block_rows) must be a multiple of 8 and >= 8; adjust "
             f"block_rows/bits/slab_slack")
     if interpret is None:
-        # Mosaic only targets TPU; elsewhere (CPU tests, debugging) the
-        # interpreter executes the same kernel semantics.
-        interpret = jax.default_backend() != "tpu"
+        # Interpret on the CPU backend only (tests, debugging); elsewhere
+        # compile, so an accelerator Mosaic cannot target fails loudly.
+        interpret = jax.default_backend() == "cpu"
 
     n = key_hi.shape[0]
     if n == 0:
@@ -302,7 +302,7 @@ def radix_sort3(key_hi: jax.Array, key_lo: jax.Array, packed: jax.Array, *,
         fp = pck2d.reshape(final_groups, slab_len)
         # Finishing sort per digit range; pads (dead triples) sink to each
         # group's tail.  One blocked sort: the sortbench-measured cheaper
-        # shape (rows beat comparator width, BENCHMARKS.md round 4).
+        # shape (rows beat comparator width, round-4 chip notes).
         sh, sl, sp_ = jax.lax.sort((fh, fl, fp), dimension=1, num_keys=3)
         oh = jnp.full((n + slab_len,), ones, jnp.uint32)
         ol = jnp.full((n + slab_len,), ones, jnp.uint32)

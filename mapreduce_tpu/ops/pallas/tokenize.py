@@ -165,7 +165,7 @@ def _sep_mask_i32(x: jax.Array) -> jax.Array:
 
 
 def _compact_planes(khi, klo, packed, has, slots: int):
-    """In-VMEM slot compaction of pair-resolution planes (VERDICT r4 #2).
+    """In-VMEM slot compaction of pair-resolution planes (round-4 review #2).
 
     ``has`` marks live pair rows (emission or poison).  Per lane, live rows
     keep their order and pack into the first ``rank`` output slots; the
@@ -181,7 +181,7 @@ def _compact_planes(khi, klo, packed, has, slots: int):
     so log2(p) passes of three (p, L) selects replace the previous
     per-slot one-hot selection — O(p log p) VPU work instead of
     O(p * slots), measured ~20 ms/chunk of kernel time at S=88
-    (BENCHMARKS.md round 4), and a scoped-VMEM footprint back near the
+    (round-4 chip notes), and a scoped-VMEM footprint back near the
     pair path's.
 
     Returns (khi[slots,L], klo[slots,L], packed[slots,L], n_spilled) where
@@ -330,8 +330,14 @@ def _tokenize_kernel(x_ref, *refs, w: int, block_rows: int, data_rows: int,
     if fused:
         # The lane's LAST data byte's successor is the next lane's first
         # byte (aux head row), not the pad row the column view shows.
+        # The select runs on int32 0/1 planes: a select between i1 planes
+        # lowers through an i8 -> i1 truncation Mosaic refuses on v5e
+        # ("Unsupported target bitwidth for truncation").
         nh_sep = _sep_mask_i32(aux[AUX_HEAD_ROW:AUX_HEAD_ROW + 1, :])
-        nxt_sep = jnp.where(m == data_rows - 1, nh_sep, nxt_sep)
+        nxt_sep = jnp.where(m == data_rows - 1,
+                            jnp.broadcast_to(nh_sep.astype(jnp.int32),
+                                             (tb, LANES)),
+                            nxt_sep.astype(jnp.int32)) != 0
     is_end = (~cur_sep) & nxt_sep
 
     intok = ~cur_sep
@@ -471,7 +477,7 @@ def _tokenize_kernel(x_ref, *refs, w: int, block_rows: int, data_rows: int,
     # suppressed token — so grams spanning it self-invalidate instead of
     # pairing phantom neighbors (this replaces a whole-chunk lax.cond
     # fallback to the XLA scan, which embedded a pathologically-slow-to-
-    # compile program in every n-gram step; VERDICT r2 #4).
+    # compile program in every n-gram step; round-2 review #4).
     lane = jax.lax.broadcasted_iota(jnp.int32, (tb, LANES), 1)
     start = lane * data_rows + m + 1 - ln_e.astype(jnp.int32)
     last_byte = (lane * data_rows + m).astype(jnp.uint32)
@@ -487,13 +493,16 @@ def _tokenize_kernel(x_ref, *refs, w: int, block_rows: int, data_rows: int,
         g = a.reshape(tb // 2, 2, LANES)
         return jnp.where(take_even, g[:, 0, :], g[:, 1, :])
 
-    live = (emit | overlong_here).reshape(tb // 2, 2, LANES)
-    even_has = live[:, 0, :]
+    # The row-pair reshape runs on an int32 0/1 plane: reshaping an i1
+    # plane makes Mosaic emit a compare on a non-vreg operand (LLO_CHECK
+    # "lhs->ProducesVreg()" on v5e).
+    live = (emit | overlong_here).astype(jnp.int32).reshape(tb // 2, 2, LANES)
+    even_has = live[:, 0, :] != 0
     khi_h = fold(khi, even_has)
     klo_h = fold(klo, even_has)
     packed_h = fold(packed, even_has)
     if compact_slots:
-        has_h = live[:, 0, :] | live[:, 1, :]
+        has_h = (live[:, 0, :] | live[:, 1, :]) != 0
         khi_c, klo_c, pck_c, n_spill = _compact_planes(
             khi_h, klo_h, packed_h, has_h, compact_slots)
         if lane_major:
@@ -564,10 +573,7 @@ def _column_pass(cols_padded: jax.Array, w: int, block_rows: int,
     # ~128 MB physical VMEM, so the override is safe headroom either way.
     # The pair path stays well under the default; one shared limit keeps
     # the call site single-owner.
-    # Older jax spells the params class TPUCompilerParams; same fields.
-    _params_cls = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
-    params = _params_cls(vmem_limit_bytes=64 * 1024 * 1024) \
+    params = pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024) \
         if compact_slots else None
     if fused:
         # The aux plane's height is a geometry knob (ISSUE 12): the spec
@@ -724,7 +730,7 @@ def tokenize_split_compact(data: jax.Array, compact_slots: int,
     The column planes hold ``compact_slots`` output rows per ``block_rows``
     byte rows (vs the pair path's ``block_rows/2``) — the downstream sort's
     input shrinks by the same ratio, which is where the chunk budget goes
-    (BENCHMARKS.md op profile).  ``spill`` (uint32) counts live rows beyond
+    (round-4 op profile).  ``spill`` (uint32) counts live rows beyond
     any (block, lane) window's budget: when it is nonzero the compact
     planes are INCOMPLETE and the caller must discard them and re-run the
     full-resolution path (``models/wordcount._map_stream`` wraps exactly
@@ -752,9 +758,10 @@ def _resolve_args(data, max_token_bytes, block_rows, interpret,
     """Shared argument validation/resolution for the split and fused entry
     points: returns ``(w, seg_len, block_rows, interpret)``."""
     if interpret is None:
-        # Mosaic only targets TPU; elsewhere (CPU tests, debugging) the
-        # interpreter executes the same kernel semantics.
-        interpret = jax.default_backend() != "tpu"
+        # The interpreter runs the kernel on the CPU backend (tests,
+        # debugging); any other backend compiles it, so an accelerator that
+        # Mosaic cannot target fails loudly instead of interpreting.
+        interpret = jax.default_backend() == "cpu"
     if data.dtype != jnp.uint8:
         raise TypeError(f"pallas tokenize expects uint8, got {data.dtype}")
     n = data.shape[0]

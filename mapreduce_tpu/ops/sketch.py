@@ -129,7 +129,7 @@ def cms_update(cms: jax.Array, key_hi: jax.Array, key_lo: jax.Array,
     Empty table slots carry count 0, so no validity mask is needed: adding
     zero to an arbitrary bucket is a no-op.  All depth rows go through ONE
     flattened scatter-add: on TPU each scatter carries a large fixed cost
-    (BENCHMARKS.md), so one scatter of depth*n updates beats depth scatters
+    (round-4/5 chip notes), so one scatter of depth*n updates beats depth scatters
     of n.
     """
     depth, width = cms.shape

@@ -351,7 +351,7 @@ def from_packed_rows(key_hi: jax.Array, key_lo: jax.Array, packed: jax.Array,
     ``sort_mode='segmin'`` also sorts two keys but recovers first
     occurrence as a segmented running-min of ``packed`` (no input-order
     precondition).  Bit-identical; REFUSED on TPU — its stream-sized
-    associative_scan wedges the chip (BENCHMARKS.md round 4).
+    associative_scan wedges the chip (round-4 chip notes).
 
     With ``rescue_slots = R > 0`` (sort3/stable2), also returns the first
     R ``packed`` values of the POISON segment — overlong-end markers
@@ -813,7 +813,7 @@ def kmv_snapshot(table: CountTable) -> tuple[jax.Array, jax.Array, jax.Array]:
     property: the kept keys stop being the smallest ever seen), so top-k
     finalized runs keep a ~1/sqrt(capacity)-error distinct estimate instead
     of degrading to the summed ``dropped_uniques`` upper bound
-    (VERDICT r3 weak #6).  Fetch the scalars and feed
+    (round-3 review weak #6).  Fetch the scalars and feed
     :func:`kmv_from_snapshot` host-side.
     """
     occ = table.occupied()

@@ -88,6 +88,53 @@ def _segmented_combine(a, b):
     return (f, v1, p1, v2, p2, ln)
 
 
+def _token_keys(v1: jax.Array, v2: jax.Array, length: jax.Array):
+    """The 64-bit key of a token from its two rolling hashes and its length
+    — the one definition every backend shares."""
+    key_hi = _fmix32(v1 ^ length)
+    key_lo = _fmix32(v2 + jnp.uint32(0x9E3779B9) * length)
+
+    # Clamp away from the two reserved keys (probability 2**-63 per token):
+    # (sent, sent) marks dead rows and (sent, sent-1) marks overlong-poison
+    # rows (:mod:`..ops.pallas.tokenize`); a real token hashing onto either
+    # would be misread structurally, so both remap to (sent, sent-2).  Every
+    # backend's clamp MUST share this rule or their keys drift.
+    sentinel = jnp.uint32(constants.SENTINEL_KEY)
+    at_sentinel = (key_hi == sentinel) & (key_lo >= sentinel - jnp.uint32(1))
+    key_lo = jnp.where(at_sentinel, sentinel - jnp.uint32(2), key_lo)
+    return key_hi, key_lo
+
+
+def last_token(windows: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """``(key_hi, key_lo, length)`` of the token ending at the LAST byte of
+    each row of ``windows`` (uint8[R, W]); ``length`` is 0 where that byte
+    is a separator, and W where the run may continue left of the window.
+
+    Bit-identical to what :func:`tokenize` emits at a row's last position,
+    but closed-form: one max-reduction finds the run start, one sum per
+    hash lane adds ``c_j * B**(W-1-j)`` over the run.  A vmapped
+    :func:`tokenize` over tens of thousands of windows costs the TPU
+    compiler many minutes; this costs seconds.
+    """
+    rows, wlen = windows.shape
+    sep = separator_mask(windows)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, wlen), 1)
+    last_sep = jnp.max(jnp.where(sep, col, -1), axis=1, keepdims=True)
+    intok = col > last_sep
+    length = (wlen - 1 - last_sep[:, 0]).astype(jnp.uint32)
+    c = windows.astype(jnp.uint32) + jnp.uint32(1)
+
+    def lane(base) -> jax.Array:
+        powers = np.array([pow(int(base), wlen - 1 - j, 1 << 32)
+                           for j in range(wlen)], dtype=np.uint32)
+        return jnp.sum(jnp.where(intok, c * powers[None, :], jnp.uint32(0)),
+                       axis=1, dtype=jnp.uint32)
+
+    key_hi, key_lo = _token_keys(lane(constants.HASH_BASE_1),
+                                 lane(constants.HASH_BASE_2), length)
+    return key_hi, key_lo, length
+
+
 def tokenize(data: jax.Array, base_offset: jax.Array | int = 0) -> TokenStream:
     """Hash every whitespace-delimited token in a flat uint8 buffer.
 
@@ -127,17 +174,8 @@ def tokenize(data: jax.Array, base_offset: jax.Array | int = 0) -> TokenStream:
     next_sep = jnp.concatenate([sep[1:], jnp.ones((1,), dtype=jnp.bool_)])
     is_end = (~sep) & next_sep
 
-    key_hi = _fmix32(v1 ^ length)
-    key_lo = _fmix32(v2 + jnp.uint32(0x9E3779B9) * length)
-
-    # Clamp away from the two reserved keys (probability 2**-63 per token):
-    # (sent, sent) marks dead rows and (sent, sent-1) marks overlong-poison
-    # rows (:mod:`..ops.pallas.tokenize`); a real token hashing onto either
-    # would be misread structurally, so both remap to (sent, sent-2).  Every
-    # backend's clamp MUST share this rule or their keys drift.
+    key_hi, key_lo = _token_keys(v1, v2, length)
     sentinel = jnp.uint32(constants.SENTINEL_KEY)
-    at_sentinel = (key_hi == sentinel) & (key_lo >= sentinel - one)
-    key_lo = jnp.where(at_sentinel, sentinel - jnp.uint32(2), key_lo)
 
     # Non-token positions carry the sentinel so they sort to the end.
     key_hi = jnp.where(is_end, key_hi, sentinel)

@@ -35,7 +35,6 @@ import jax.numpy as jnp
 
 from mapreduce_tpu.obs import registry as obs_registry
 from mapreduce_tpu.ops import table as table_ops
-from mapreduce_tpu.parallel.compat import axis_size as _axis_size
 
 T = TypeVar("T")
 MergeFn = Callable[[T, T], T]
@@ -54,7 +53,7 @@ def _count_build(strategy: str, axis) -> None:
     the profiler timeline (``obs.span`` regions around the dispatch).
     """
     try:
-        d = _axis_size(axis)
+        d = jax.lax.axis_size(axis)
     except Exception:
         d = 0
     obs_registry.get_registry().counter(
@@ -65,7 +64,7 @@ def tree_merge(state: T, merge: MergeFn, axis: str) -> T:
     """Butterfly all-reduce: after log2(D) ppermute+merge rounds every device
     holds the merge of all D states.  Deterministic and replicated.
     """
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n & (n - 1):
         return gather_merge(state, merge, axis)
     _count_build("tree", axis)
@@ -80,7 +79,7 @@ def tree_merge(state: T, merge: MergeFn, axis: str) -> T:
 
 def gather_merge(state: T, merge: MergeFn, axis: str) -> T:
     """all_gather every state then fold left.  Any axis size; replicated."""
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     _count_build("gather", axis)
     gathered = jax.tree.map(lambda x: jax.lax.all_gather(x, axis), state)
     take = lambda i: jax.tree.map(lambda x: x[i], gathered)
@@ -157,7 +156,7 @@ def key_range_merge(table: table_ops.CountTable, axis,
     (the mesh is flattened; the single a2a round trades the ICI/DCN
     hierarchy for one scheduled collective).
     """
-    d = _axis_size(axis)
+    d = jax.lax.axis_size(axis)
     cap = table.capacity
     if d == 1:
         return table

@@ -69,13 +69,13 @@ def initialize(coordinator_address: Optional[str] = None,
     # NOTE: must not touch jax.process_count()/jax.devices() here — any such
     # call initializes the XLA backend, after which
     # jax.distributed.initialize() refuses to run.
-    if _is_initialized():
+    if jax.distributed.is_initialized():
         return
     explicit = coordinator_address or num_processes or process_id
     env = (os.environ.get("COORDINATOR_ADDRESS")
            or os.environ.get("JAX_COORDINATOR_ADDRESS")
            or os.environ.get("MEGASCALE_COORDINATOR_ADDRESS"))
-    if not explicit and not env and not _on_cloud_tpu():
+    if not explicit and not env and not _on_pod():
         return  # single-host run: nothing to join
     # Init wall-clock into the registry: a pod bring-up that creeps from
     # seconds to minutes (DNS, a slow peer, a flaky coordinator) shows up
@@ -109,21 +109,16 @@ def initialize(coordinator_address: Optional[str] = None,
               init_s=round(init_s, 3))
 
 
-def _is_initialized() -> bool:
-    """``jax.distributed.is_initialized`` on any jax: the public predicate
-    only exists on newer versions; older ones expose the same fact as the
-    distributed client singleton (set exactly while initialized)."""
-    if hasattr(jax.distributed, "is_initialized"):
-        return jax.distributed.is_initialized()
-    from jax._src import distributed as _dist
-
-    return getattr(_dist.global_state, "client", None) is not None
-
-
-def _on_cloud_tpu() -> bool:
-    """True when running under a TPU pod launcher that exports multi-worker
-    topology env (single-worker VMs lack TPU_WORKER_HOSTNAMES)."""
-    return bool(os.environ.get("TPU_WORKER_HOSTNAMES"))
+def _on_pod() -> bool:
+    """True when a TPU pod launcher exported a multi-worker topology: a
+    comma-separated list of two or more host names.  A single host may
+    carry ``TPU_WORKER_HOSTNAMES`` too — naming only itself, or holding
+    libtpu's warning text when a topology is merely described — and has
+    nothing to join."""
+    hosts = [h.strip() for h in
+             os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",")]
+    return len(hosts) > 1 and all(h and not any(c.isspace() for c in h)
+                                  for h in hosts)
 
 
 #: {wall, mono} sampled together at jax.distributed init (lazily on

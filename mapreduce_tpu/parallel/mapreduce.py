@@ -33,10 +33,10 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mapreduce_tpu.parallel import collectives
-from mapreduce_tpu.parallel.compat import axis_size, shard_map
 from mapreduce_tpu.parallel import mesh as mesh_mod
 
 
@@ -194,7 +194,7 @@ class Engine:
         """Linear index of this shard across all sharded axes (row-major)."""
         idx = jax.lax.axis_index(self.axes[0])
         for a in self.axes[1:]:
-            idx = idx * axis_size(a) + jax.lax.axis_index(a)
+            idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         return idx.astype(jnp.uint32)
 
     @property

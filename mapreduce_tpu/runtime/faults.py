@@ -124,7 +124,7 @@ class PreemptionFault(FaultError):
 
 class TokenTimeout(FaultError):
     """A completion-token wait exceeded ``FailurePolicy.token_timeout_s``:
-    the device (or its relay link) is hung.  Transient — the replay path
+    the device is hung.  Transient — the replay path
     re-dispatches from the window anchor."""
 
     fault_class = "transient"

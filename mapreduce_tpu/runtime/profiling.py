@@ -15,6 +15,7 @@ Usage::
 from __future__ import annotations
 
 import contextlib
+import os
 from typing import Iterator, Optional
 
 
@@ -40,27 +41,25 @@ def region(name: str) -> Iterator[None]:
         yield
 
 
-def enable_compile_cache(cache_dir: Optional[str] = None) -> None:
-    """Point JAX at a persistent compilation cache.
+#: The compile cache's one place when the environment names none: inside
+#: the checkout (git-ignored), so every run of this tree hits the same cache.
+CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
 
-    First compiles of the streaming step are multi-minute programs; the cache
-    makes every later same-shape run (CLI or bench, same process or not)
-    skip them.  Default location: ``~/.cache/jax_mapreduce``, overridable via
-    ``MAPREDUCE_COMPILE_CACHE`` (set it empty to disable).  Best-effort: a
-    cache failure must never take down a run.
+
+def enable_compile_cache() -> None:
+    """Place JAX's persistent compilation cache.
+
+    First compiles of the streaming step take minutes on the chip; the
+    cache makes every later same-shape run skip them.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and nothing is
+    set here; otherwise the cache lives at :data:`CACHE_DIR`.  Whether the
+    cache is on at all stays JAX's own ``jax_enable_compilation_cache``
+    switch (the test suite turns it off).
     """
-    import os
-
-    if cache_dir is None:
-        cache_dir = os.environ.get(
-            "MAPREDUCE_COMPILE_CACHE",
-            os.path.expanduser("~/.cache/jax_mapreduce"))
-    if not cache_dir:
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return
     import jax
 
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass
+    jax.config.update("jax_compilation_cache_dir", CACHE_DIR)

@@ -118,8 +118,8 @@ _INT_KNOBS = ("inflight_groups", "prefetch_depth", "superstep",
 # window holds >16 chunks of staged input live (the documented memory
 # cost), superstep 32 at the default chunk stages 1 GB per device per
 # dispatch, and chunk_bytes beyond 64 MB is refused by the pallas packing
-# envelope while below 1 MB dispatch overhead dominates (BENCHMARKS.md
-# round 4).
+# envelope while below 1 MB dispatch overhead dominates (round-4 chip
+# notes).
 INFLIGHT_MAX = 16
 PREFETCH_MAX = 16
 SUPERSTEP_MAX = 32

@@ -9,33 +9,44 @@ reference (whitespace-split word count, insertion-ordered report,
 
 from __future__ import annotations
 
+from collections import Counter
+
 from mapreduce_tpu import constants
 
-_SEPARATORS = bytes(constants.SEPARATOR_BYTES)
+# Every separator becomes a space, then ``bytes.split()`` splits on runs of
+# ASCII whitespace — which must all be separators, or split() would cut
+# tokens the framework keeps whole.
+_TO_SPACE = bytes.maketrans(bytes(constants.SEPARATOR_BYTES),
+                            b" " * len(constants.SEPARATOR_BYTES))
+assert all(b in constants.SEPARATOR_BYTES
+           for b in range(256) if not bytes([b]).split())
+
+#: Bytes split per slab in :func:`word_counts`: bounds the token list's
+#: host memory on GiB corpora.
+_SLAB = 16 << 20
 
 
 def split_words(data: bytes) -> list[bytes]:
     """All tokens in order, splitting on the framework's separator set."""
-    out = []
-    word = bytearray()
-    for b in data:
-        if b in _SEPARATORS:
-            if word:
-                out.append(bytes(word))
-                word = bytearray()
-        else:
-            word.append(b)
-    if word:
-        out.append(bytes(word))
-    return out
+    return bytes(data).translate(_TO_SPACE).split()
 
 
 def word_counts(data: bytes) -> dict[bytes, int]:
     """Insertion-ordered {word: count} — the golden semantics (SURVEY §2)."""
-    counts: dict[bytes, int] = {}
-    for w in split_words(data):
-        counts[w] = counts.get(w, 0) + 1
-    return counts
+    text = bytes(data).translate(_TO_SPACE)
+    counts: Counter = Counter()
+    start = 0
+    while start < len(text):
+        end = len(text)
+        if end - start > _SLAB:
+            # Cut after a separator so no token straddles two slabs.
+            cut = text.rfind(b" ", start, start + _SLAB)
+            if cut < 0:
+                cut = text.find(b" ", start + _SLAB)
+            end = len(text) if cut < 0 else cut + 1
+        counts.update(text[start:end].split())
+        start = end
+    return dict(counts)
 
 
 def total_count(data: bytes) -> int:

@@ -18,7 +18,7 @@ distinct words at ~n^2 / 2^65:
   ======================  ========================
 
 At the BASELINE 100 GB scale the risk is real enough to want a DETECTION
-path, not just arithmetic (VERDICT r4 missing #4).  This module is that
+path, not just arithmetic (round-4 review missing #4).  This module is that
 path: recount a sample of reported words EXACTLY on the host — byte-string
 keyed, no hashing anywhere — and compare.  A collision is visible as a
 reported count exceeding the true count (the victim word's occurrences were

@@ -1,7 +1,7 @@
 """Worker process for the run_job_global multi-process test.
 
 Drives the EXECUTOR's global-SPMD entry point (``executor.run_job_global``,
-VERDICT r3 #5) end-to-end: ``jax.distributed.initialize`` over gloo, a
+round-3 review #5) end-to-end: ``jax.distributed.initialize`` over gloo, a
 global mesh spanning both processes, per-process ``host_shards`` staging,
 coordinator-only checkpointing — and, when ``crash_at_step >= 0``, a
 deterministic injected failure on EVERY process at that step (both raise

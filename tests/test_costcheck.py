@@ -565,7 +565,7 @@ def test_cost_gate_refuses_baseline_missing_geometry(mesh8, tmp_path,
 
 
 class SharedJaxprJob(_ScalarJob):
-    """Two same-shaped jnp.sort calls: JAX's pjit cache hands both the
+    """Two same-shaped jnp.sort calls: JAX's jit cache hands both the
     SAME inner jaxpr (and Var objects).  A later cumsum consumes the
     FIRST sort's result — NOT adjacent (the second sort sits between) —
     so no sort->cumsum candidate may appear.  Guards the value-id

@@ -308,7 +308,7 @@ def test_checkpoint_future_format_rejected(tmp_path):
 
 @pytest.mark.slow
 def test_step_retry_recovers_transient_failure(tmp_path, rng, monkeypatch):
-    """VERDICT r1 #5 'done' case: an injected one-shot step failure recovers
+    """round-1 review #5 'done' case: an injected one-shot step failure recovers
     via the in-memory known-good snapshot, without a checkpoint file, and
     produces exact counts."""
     from mapreduce_tpu.parallel.mapreduce import Engine
@@ -356,7 +356,7 @@ def test_step_retry_exhausted_surfaces(tmp_path, rng, monkeypatch):
 
 @pytest.mark.slow
 def test_mid_superstep_checkpoint_granularity(tmp_path, rng, monkeypatch):
-    """VERDICT r1 #10 'done' case: with checkpoint_every finer than the
+    """round-1 review #10 'done' case: with checkpoint_every finer than the
     superstep, a kill mid-run resumes from the last per-step checkpoint —
     replaying at most checkpoint_every (=1 here) chunks per device, not a
     whole superstep."""
@@ -448,7 +448,7 @@ def test_ledger_one_record_per_step(tmp_path, rng):
 def test_flight_dump_on_injected_step_failure(tmp_path, rng, monkeypatch):
     """ISSUE 2 acceptance: an injected step failure leaves a flight-recorder
     dump (recent events + context + metrics) and a ledger failure record —
-    forensics instead of nothing (the benchwatch wedge scenario)."""
+    forensics instead of nothing."""
     import json as _json
 
     from mapreduce_tpu import obs

@@ -69,13 +69,13 @@ def test_classify_taxonomy():
     # 'oom' counts as a whole word only — 'bloom'/'room'/'zoom' inside a
     # non-permanent-typed message must not charge the resource budget
     # (and walk the ladder); a real 'OOM when allocating' still does.
-    assert faults.classify(RuntimeError("bloom filter relay failed")) \
+    assert faults.classify(RuntimeError("bloom filter sync failed")) \
         == "transient"
     assert faults.classify(OSError("no room in zoom buffer")) == "transient"
     assert faults.classify(RuntimeError("OOM when allocating")) == "resource"
     # Unknown -> transient: the legacy retry=N semantics retried ANY
     # exception, and the default policy must keep doing exactly that.
-    assert faults.classify(RuntimeError("flaky relay")) == "transient"
+    assert faults.classify(RuntimeError("flaky link")) == "transient"
     assert faults.classify(OSError("read failed")) == "transient"
 
 

@@ -66,7 +66,7 @@ def test_streamed_grep_matches_oracle(tmp_path, small_corpus):
 
 
 def test_streamed_grep_line_split_across_rows_exact(tmp_path):
-    """VERDICT r1 #9 'done' case: a matching line whose segments land in
+    """round-1 review #9 'done' case: a matching line whose segments land in
     different chunk rows (and different steps) must count once."""
     # Lines far longer than chunk_bytes, separated by spaces so the reader
     # cuts mid-line at separator boundaries; matches in several segments.
@@ -441,7 +441,7 @@ def test_grep_resume_across_file_seam_keeps_boundary_reset(tmp_path, monkeypatch
 
 
 def test_bare_map_chunk_sequential_exact_lines():
-    """VERDICT r3 #8: the no-axis map_chunk fallback must be exact when rows
+    """round-3 review #8: the no-axis map_chunk fallback must be exact when rows
     are driven sequentially (map_chunk + combine, no mesh) — the single-row
     transfer terms make lines match the oracle even for lines spanning rows."""
     import jax.numpy as jnp

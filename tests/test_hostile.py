@@ -1,4 +1,4 @@
-"""Hostile-input fixtures beyond clean ASCII (VERDICT r4 next #9).
+"""Hostile-input fixtures beyond clean ASCII (round-4 review next #9).
 
 The bench generators are clean ASCII; real corpora (enwik dumps, WET crawl
 text) carry UTF-8 multibyte words, NUL bytes, markup, and very long

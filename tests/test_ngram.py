@@ -159,7 +159,7 @@ def test_gram_table_sentinel_boundary_at_max_pos_gate():
 @pytest.mark.slow
 def test_streamed_ngrams_single_device_exact(tmp_path):
     """Streamed == single-buffer, bit-exact, on a one-device mesh whose
-    2 KB chunks force grams to straddle every row seam (VERDICT r2 #5:
+    2 KB chunks force grams to straddle every row seam (round-2 review #5:
     the old (n-1)*(chunks-1) undercount envelope is gone — the seam carry
     forms every crossing window exactly once)."""
     from mapreduce_tpu.parallel.mesh import data_mesh
@@ -286,7 +286,7 @@ def test_pallas_ngrams_match_oracle_and_xla(small_corpus, n):
 
 
 def test_pallas_gram_straddles_lane_seam():
-    """VERDICT r1 #4 'done' case: the kernel's 128-lane seams cut the buffer
+    """round-1 review #4 'done' case: the kernel's 128-lane seams cut the buffer
     every seg_len bytes; seam emissions are concatenated before the position
     sort, so grams whose tokens straddle a seam must form exactly.  The
     buffer is sized to one pallas chunk (seg_len = 66), so a corpus covering
@@ -304,7 +304,7 @@ def test_pallas_ngram_overlong_poison(small_corpus):
     """A chunk containing a token longer than the kernel window W: poison
     rows break the pairing chain at the suppressed token, so its neighbors
     never pair into phantom grams; the grams it would have joined are
-    dropped and accounted (VERDICT r2 #4 — this replaced the whole-chunk
+    dropped and accounted (round-2 review #4 — this replaced the whole-chunk
     lax.cond XLA fallback that embedded a pathologically-slow-to-compile
     branch in every n-gram program)."""
     data = small_corpus[:4000] + b" " + b"x" * 40 + b" " + small_corpus[4000:]

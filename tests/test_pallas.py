@@ -169,7 +169,7 @@ def test_packed_stream_consistency(small_corpus):
                                   (packed & 63)[has])
 
 
-# --- slot compaction (VERDICT r4 #2) -----------------------------------------
+# --- slot compaction (round-4 review #2) -----------------------------------------
 
 
 def _compact_table(data: bytes, slots: int, w: int = W, block_rows: int = 64):
@@ -280,10 +280,10 @@ def test_compact_slots_validation():
 
 @pytest.mark.slow
 def test_natural_corpus_backends_agree():
-    """VERDICT r3 #6: on the natural-proxy corpus the pallas and xla
+    """round-3 review #6: on the natural-proxy corpus the pallas and xla
     backends must produce the SAME table — tools/density.py measured zero
     >W tokens there (max 18 bytes), so the >W envelope costs nothing on
-    the bench corpora (BENCHMARKS.md round-4 section quantifies this)."""
+    the bench corpora (round-4 chip notes section quantifies this)."""
     import os
     import sys
     sys.path.insert(0, os.path.dirname(os.path.dirname(

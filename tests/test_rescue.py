@@ -1,5 +1,5 @@
 """Overlong-token rescue: the pallas backend must agree with the XLA oracle
-on corpora with >W-byte tokens (VERDICT r3 #6; ops/rescue.py).
+on corpora with >W-byte tokens (round-3 review #6; ops/rescue.py).
 
 The XLA backend counts any token length exactly, so it IS the oracle: with
 rescue on, pallas runs must match it bit-for-bit whenever every overlong
@@ -133,7 +133,7 @@ class TestRescueEnvelope:
 
     @pytest.mark.slow
     def test_tier_escalates_past_primary_budget(self, rng):
-        """VERDICT r4 weak #4: overlong counts past the primary budget
+        """round-4 review weak #4: overlong counts past the primary budget
         escalate to the second tier under a lax.cond instead of silently
         leaving the residual in dropped_* — URL-dense chunks stay exact
         with no hand-sizing."""

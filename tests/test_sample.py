@@ -113,7 +113,7 @@ def test_sample_cli(tmp_path, capsys):
 
 
 def test_pallas_sample_matches_xla(small_corpus):
-    """VERDICT r2 #6: the sample job honors config.resolved_backend(), and
+    """round-2 review #6: the sample job honors config.resolved_backend(), and
     the pallas fused-kernel path draws the IDENTICAL sample (priorities
     depend only on (chunk_id, pos), shared by both backends)."""
     base = dict(chunk_bytes=1 << 14, table_capacity=1 << 10)

@@ -1,8 +1,8 @@
 """sort_mode='stable2': lane-major kernel layout + stable 2-key aggregation.
 
-The round-5 sort-floor attack (VERDICT r4 next #1b): drop the third
+The round-5 sort-floor attack (round-4 review next #1b): drop the third
 comparator key from the aggregation sort — ~40% of the sort's compute on
-the chip (BENCHMARKS.md round-4 sortbench) — by making the kernel emit its
+the chip (round-4 chip notes sortbench) — by making the kernel emit its
 compacted planes in global byte-position order (transposed lane-major
 blocks) so a STABLE two-key sort recovers first occurrence from tie order.
 
@@ -166,25 +166,12 @@ def test_stable2_streamed_executor(tmp_path, rng):
     assert a.as_dict() == oracle.word_counts(corpus)
 
 
-# The exact jax release whose pallas INTERPRET machinery deadlocks in
-# _allocate_buffer/_barrier under an 8-wide shard_map on a one-core box
-# (round-5 faulthandler dump) — the reason test_stable2_streamed_executor
-# runs stable2 on a 4-device mesh.  Pinned HERE so the workaround's
-# coverage gap cannot outlive its cause (ADVICE r5).
-_INTERPRET_DEADLOCK_JAX = "0.4.37"
-
-
+# The round-5 jax's pallas INTERPRET machinery deadlocked in
+# _allocate_buffer/_barrier under an 8-wide shard_map on a one-core box —
+# the reason test_stable2_streamed_executor runs stable2 on a 4-device
+# mesh.  This test restores that coverage; @slow keeps a possible hang out
+# of tier-1.
 @pytest.mark.slow
-@pytest.mark.skipif(
-    jax.__version__ == _INTERPRET_DEADLOCK_JAX,
-    reason="pinned to the jax pallas INTERPRET _allocate_buffer/_barrier "
-           "deadlock: stable2's lane-major kernel under an 8-wide "
-           "shard_map wedges interpret threads on this jax version "
-           "(round-5 faulthandler dump).  Any jax bump re-enables this "
-           "test automatically; if it then deadlocks again, re-pin "
-           "_INTERPRET_DEADLOCK_JAX to the new version and report "
-           "upstream.  @slow keeps a possible hang out of tier-1's "
-           "870 s budget either way.")
 def test_stable2_streamed_executor_8wide(tmp_path, rng):
     """The coverage test_stable2_streamed_executor gives up to dodge the
     interpret deadlock: streamed stable2 on the FULL 8-device mesh, vs

@@ -283,7 +283,7 @@ def test_segmin_end_to_end_equals_sort3(small_corpus):
 
 @pytest.mark.slow
 def test_kmv_distinct_under_capacity_pressure(rng):
-    """VERDICT r2 #8: under table spill, ``distinct`` is the table's free
+    """round-2 review #8: under table spill, ``distinct`` is the table's free
     KMV estimate (the full table's kept keys are the bottom-capacity key
     hashes), bounded ~1/sqrt(capacity) — not the summed per-chunk bound.
     At capacity 4096 over ~12x more distinct words, the error must be a few
@@ -307,7 +307,7 @@ def test_kmv_distinct_under_capacity_pressure(rng):
 
 @pytest.mark.slow
 def test_kmv_distinct_survives_topk_finalize(tmp_path, rng):
-    """VERDICT r3 weak #6: top-k finalized runs keep the tight KMV distinct
+    """round-3 review weak #6: top-k finalized runs keep the tight KMV distinct
     via the pre-reorder snapshot (TopKTable) — the Common-Crawl top-k
     config is exactly where spill is likely."""
     from mapreduce_tpu.parallel.mesh import data_mesh
@@ -348,7 +348,7 @@ def test_kmv_distinct_streamed(tmp_path, rng):
     assert err < 0.05, f"KMV distinct {r.distinct} vs true {n_distinct}"
 
 
-# --- 64-bit count lanes: forced-wrap coverage (VERDICT r3 #4) ----------------
+# --- 64-bit count lanes: forced-wrap coverage (round-3 review #4) ----------------
 
 
 def _seed_counts(t: tbl.CountTable, lo_vals, hi_vals=None) -> tbl.CountTable:

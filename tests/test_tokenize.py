@@ -96,3 +96,21 @@ def test_base_offset_shifts_positions():
     s = tok.tokenize(_as_buf(b"ab cd"), base_offset=100)
     ends = np.flatnonzero(np.asarray(s.count))
     assert np.asarray(s.pos)[ends].tolist() == [100, 103]
+
+
+@pytest.mark.parametrize("width", [1, 7, 64, 192])
+@pytest.mark.parametrize("alphabet", [b" a", b"\x00\t\n ab\xff", b"ab\xc8"])
+def test_last_token_matches_tokenize(width, alphabet):
+    """The closed-form hash of the token ending at each row's last byte
+    (the overlong rescue's) equals tokenize()'s emission there, bit for
+    bit — including runs that fill the whole window."""
+    import jax
+
+    rng = np.random.default_rng(width)
+    rows = rng.choice(np.frombuffer(alphabet, np.uint8), size=(200, width))
+    s = jax.vmap(tok.tokenize)(jnp.asarray(rows))
+    hi, lo, ln = tok.last_token(jnp.asarray(rows))
+    assert np.array_equal(np.asarray(ln), np.asarray(s.length[:, -1]))
+    ends = np.asarray(s.count[:, -1]) > 0
+    assert np.array_equal(np.asarray(hi)[ends], np.asarray(s.key_hi[:, -1])[ends])
+    assert np.array_equal(np.asarray(lo)[ends], np.asarray(s.key_lo[:, -1])[ends])

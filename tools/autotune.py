@@ -3,8 +3,7 @@
 ``mapreduce_tpu/tuning/`` over N short streamed probe passes on a
 bench-style corpus until the config converges, the oscillation guard
 trips, or the pass budget runs out — then emit a ``tuned.json`` profile
-keyed by (family, backend, corpus shape) and record the winner as a
-value-aware ``BENCH_LAST_GOOD.json`` entry with the full decision trail.
+keyed by (family, backend, corpus shape) with the full decision trail.
 
 Each probe pass streams the corpus through ``executor.run_job`` with
 telemetry into its own ledger; the tuner then reads exactly what the run
@@ -50,10 +49,6 @@ from mapreduce_tpu.tuning import engine  # noqa: E402 (jax-free)
 
 FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "fixtures")
-LAST_GOOD_PATH = os.path.join(REPO, "BENCH_LAST_GOOD.json")
-#: Mirrors bench.py's value-aware discipline for the tuned record: a
-#: same-profile regression this deep cannot displace the best-known entry.
-REGRESSION_FRAC = 0.25
 
 
 def _read_fixture(name: str) -> list:
@@ -87,7 +82,7 @@ def _probe_config(knobs: dict):
                   # (resolved_combiner_slots is 0 elsewhere): a probe that
                   # left map_impl at 'split' would re-measure the IDENTICAL
                   # program while reporting the combiner engaged — the same
-                  # pairing benchwatch._tuned_env applies to the tuned rows.
+                  # pairing the tuned rows apply.
                   map_impl="fused" if combiner == "hot-cache"
                   else Config.map_impl,
                   # Placed-reduction knobs (ISSUE 20): round-trip as a
@@ -149,7 +144,7 @@ def _make_measure(corpus_path: str, mesh, ledger_dir: str,
     return measure, state
 
 
-# -- tuned.json + BENCH_LAST_GOOD --------------------------------------------
+# -- tuned.json -------------------------------------------------------------
 
 def _trail_summary(result: dict) -> list:
     """The per-pass decision trail, compacted for the profile/record."""
@@ -175,56 +170,6 @@ def write_profile(out_path: str, key: str, entry: dict) -> None:
         json.dump({"tuner_version": engine.TUNER_VERSION,
                    "profiles": profiles}, f, indent=1)
         f.write("\n")
-
-
-def record_last_good(key: str, entry: dict, backend: str,
-                     path: str = LAST_GOOD_PATH,
-                     slot: str = "tuned") -> bool:
-    """Record the tuned winner as a value-aware best-known entry under
-    ``best.<slot>`` in BENCH_LAST_GOOD.json — same discipline as bench.py's
-    per-metric records: CPU smoke runs refused (not TPU evidence), a
-    >25% same-profile regression cannot displace the best-known record,
-    every refusal leaves a stderr trace.  ``slot`` separates record
-    families that must not displace each other (the geometry search's
-    winner rides ``best.geometry``, ISSUE 12)."""
-    def refused(msg: str) -> bool:
-        print(f"[autotune] last-good write refused: {msg}", file=sys.stderr,
-              flush=True)
-        return False
-
-    if backend == "cpu":
-        return refused("cpu backend (smoke run, not TPU evidence)")
-    try:
-        with open(path, encoding="utf-8") as f:
-            prev = json.load(f)
-    except (OSError, ValueError):
-        prev = {}
-    best = dict(prev.get("best") or {})
-    rec = best.get(slot)
-    val = entry.get("measured_gbps")
-    if val is None:
-        return refused("no measured GB/s for the winner")
-    if rec is not None and rec.get("profile") == key:
-        old = rec.get("value", 0.0)
-        if val < (1.0 - REGRESSION_FRAC) * old:
-            return refused(f"tuned profile {key!r} regressed {old} -> {val} "
-                           f"(> {REGRESSION_FRAC:.0%}); best-known kept")
-        if val < old:
-            return refused(f"tuned profile {key!r} below best-known "
-                           f"({val} < {old}, within {REGRESSION_FRAC:.0%}); "
-                           "best-known kept")
-    best[slot] = {"value": val, "profile": key,
-                     "recorded_at": entry.get("recorded_at"),
-                     "config": entry.get("config"),
-                     "stopped": entry.get("stopped"),
-                     "trail": entry.get("trail")}
-    try:
-        with open(path, "w", encoding="utf-8") as f:
-            json.dump({**prev, "best": best}, f)
-            f.write("\n")
-    except OSError:
-        return refused("BENCH_LAST_GOOD.json not writable")
-    return True
 
 
 # -- offline search ----------------------------------------------------------
@@ -256,8 +201,7 @@ def run_search(args) -> int:
     backend = jax.devices()[0].platform
     ledger_dir = args.keep_ledgers or tempfile.mkdtemp(prefix="autotune_")
     os.makedirs(ledger_dir, exist_ok=True)
-    with tempfile.NamedTemporaryFile(dir="/tmp", suffix=".txt",
-                                     delete=False) as f:
+    with tempfile.NamedTemporaryFile(suffix=".txt", delete=False) as f:
         f.write(corpus)
         path = f.name
     start = {"chunk_bytes": args.chunk_mb << 20,
@@ -303,11 +247,9 @@ def run_search(args) -> int:
              "recorded_at": time.strftime("%Y-%m-%dT%H:%M:%SZ",
                                           time.gmtime())}
     write_profile(args.out, key, entry)
-    recorded = record_last_good(key, entry, backend)
     log(f"{result['stopped']} after {result['passes']} pass(es); "
         f"winner {result['winner']} @ {entry['measured_gbps']} GB/s -> "
-        f"{args.out} [{key}]"
-        + ("" if recorded else " (LAST_GOOD unchanged)"))
+        f"{args.out} [{key}]")
     print(json.dumps({"metric": "autotune_winner", "profile": key, **entry}))
     return 0
 
@@ -421,9 +363,7 @@ def selftest() -> int:
         for p in res["trail"]:
             engine.validate_knobs(p["proposal"])
 
-    # Profile writing + the value-aware LAST_GOOD discipline, exercised
-    # against a temp file: best-known kept on a deep same-profile
-    # regression, displaced by a better value, cpu refused.
+    # Profile writing, exercised against a temp file.
     import tempfile
 
     with tempfile.TemporaryDirectory() as d:
@@ -438,17 +378,6 @@ def selftest() -> int:
         assert set(blob["profiles"]) == {
             "wordcount/tpu/zipf-32mb-chunk2mb",
             "wordcount/tpu/natural-64mb-chunk4mb"}, blob
-        lg = os.path.join(d, "LAST_GOOD.json")
-        assert record_last_good("k", entry, "tpu", path=lg)
-        assert not record_last_good("k", entry, "cpu", path=lg)
-        worse = {**entry, "measured_gbps": 0.1}
-        assert not record_last_good("k", worse, "tpu", path=lg)
-        with open(lg, encoding="utf-8") as f:
-            assert json.load(f)["best"]["tuned"]["value"] == 0.5
-        better = {**entry, "measured_gbps": 0.9}
-        assert record_last_good("k", better, "tpu", path=lg)
-        with open(lg, encoding="utf-8") as f:
-            assert json.load(f)["best"]["tuned"]["value"] == 0.9
     assert had_jax or "jax" not in sys.modules, \
         "selftest must stay jax-free"
     print("autotune selftest ok (reader walk -> prefetch 16 in "
@@ -456,8 +385,7 @@ def selftest() -> int:
           f"{r2['winner']['superstep']} with inflight untouched, "
           f"skew walk -> combiner {rs['winner']['combiner']} in "
           f"{rs['passes']} passes, "
-          f"oscillation stopped in {r3['passes']}, profiles + value-aware "
-          "LAST_GOOD ok)")
+          f"oscillation stopped in {r3['passes']}, profiles ok)")
     return 0
 
 

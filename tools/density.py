@@ -3,13 +3,13 @@
 
 Two round-4 design questions need numbers, not guesses:
 
-1. **Compaction slot budget** (VERDICT r3 #2): the pallas kernel's output is
+1. **Compaction slot budget** (round-3 review #2): the pallas kernel's output is
    one row per 2 input bytes because that is the worst-case emission rate;
    a slot-compacted output of B slots per W-byte window is lossless only
    when no window ever holds more than B token ends.  What budget do real
    corpora need, at the kernel's (block_rows x 128-lane) window geometry?
 
-2. **>W-token envelope** (VERDICT r3 #6): the pallas backend drops tokens
+2. **>W-token envelope** (round-3 review #6): the pallas backend drops tokens
    longer than W=32 bytes into dropped_* accounting while the XLA backend
    counts them exactly.  How big is that divergence on natural-ish text?
 

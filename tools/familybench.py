@@ -1,10 +1,10 @@
 #!/usr/bin/env python3
 """Per-family cost rows: what each shipped job family pays vs plain
-word count on the SAME corpus (VERDICT r5 #5/#6).
+word count on the SAME corpus.
 
 Every family the CLI ships now gets a measured end-to-end number from one
-tool, one family per invocation so benchwatch gives each its own capture
-and deadline:
+tool, one family per invocation so each gets its own capture and
+deadline:
 
     python tools/familybench.py plain     # the denominator row
     python tools/familybench.py grep      # --grep the (literal pattern)
@@ -18,8 +18,7 @@ Each run streams the same cached synthetic corpus file through the real
 CLI in a fresh subprocess (fresh jax, ambient platform — TPU on the chip,
 CPU elsewhere) and prints one JSON line: family, wall seconds, corpus
 bytes, GB/s, and the verify line when applicable.  Overhead-vs-plain is
-computed by the reader from the plain row of the same session
-(BENCHMARKS.md "family overhead" table).
+computed by the reader from the plain row of the same session.
 
 Env knobs: FAMILY_MB (default 64), FAMILY_CORPUS (zipf|natural|webby|
 markup, default zipf), FAMILY_CHUNK_MB (default 32), FAMILY_TIMEOUT_S

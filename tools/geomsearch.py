@@ -17,8 +17,8 @@ Three stages, each cheaper than the next is allowed to be:
 3. ``--probe``: measured on-device ranking — one telemetered streamed
    probe pass per shortlisted candidate through the PR-10 probe
    machinery (``tools/autotune.py``), winner written to ``tuned.json``
-   (profile key ``<family>-geometry/<backend>/<corpus>``) and recorded
-   as a value-aware ``BENCH_LAST_GOOD`` entry with the ranked trail.
+   (profile key ``<family>-geometry/<backend>/<corpus>``) with the
+   ranked trail.
    ``Config.geometry='auto'`` / CLI ``--geometry auto`` resolve from
    exactly these profiles.
 
@@ -183,8 +183,7 @@ def run_probe(args, geom_mod) -> int:
     backend = jax.devices()[0].platform
     ledger_dir = args.keep_ledgers or tempfile.mkdtemp(prefix="geomsearch_")
     os.makedirs(ledger_dir, exist_ok=True)
-    with tempfile.NamedTemporaryFile(dir="/tmp", suffix=".txt",
-                                     delete=False) as f:
+    with tempfile.NamedTemporaryFile(suffix=".txt", delete=False) as f:
         f.write(corpus)
         path = f.name
     measured = []
@@ -233,10 +232,7 @@ def run_probe(args, geom_mod) -> int:
              "recorded_at": time.strftime("%Y-%m-%dT%H:%M:%SZ",
                                           time.gmtime())}
     autotune.write_profile(args.out, key, entry)
-    recorded = autotune.record_last_good(key, entry, backend,
-                                         slot="geometry")
-    log(f"winner {best.label} @ {best_gbps} GB/s -> {args.out} [{key}]"
-        + ("" if recorded else " (LAST_GOOD unchanged)"))
+    log(f"winner {best.label} @ {best_gbps} GB/s -> {args.out} [{key}]")
     print(json.dumps({"metric": "geomsearch_winner", "profile": key,
                       **entry}))
     return 0

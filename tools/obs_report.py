@@ -10,9 +10,9 @@ Reads the JSONL run ledger the executor writes under ``--ledger``
   a bound classification — **dispatch-bound** (device queue full: compute
   or link is the ceiling), **read-bound** (the reader cannot keep ahead),
   or **stage-bound** (host assembly + H2D placement dominates) — the
-  question VERDICT r4's 3x streamed-vs-H2D gap needed answered;
+  question the round-4 3x streamed-vs-H2D gap needed answered;
 * anomalies: step-time spikes (elapsed > 3x the median step — recompiles
-  and relay stalls look exactly like this), device memory growth across
+  and device stalls look exactly like this), device memory growth across
   the run (leaked live arrays), retries, failures (with the flight-dump
   path), checkpoint cadence, compile cost;
 * the **timeline** section (ISSUE 7), when the ledger carries ``group``
@@ -302,7 +302,7 @@ def analyze_run(records: list) -> dict:
     wall = end.get("elapsed_s") if end else None
 
     # Step-time spikes: elapsed_s is wall since the previous record, so a
-    # recompile or a stalled relay shows as one fat step.
+    # recompile or a stalled device shows as one fat step.
     elapsed = [(r.get("step_first"), r["elapsed_s"])
                for r in steps if r.get("elapsed_s") is not None]
     med = _median([e for _, e in elapsed])
@@ -621,7 +621,7 @@ def render_run(a: dict, out) -> None:
     for s in a["spikes"]:
         out.write(f"  ANOMALY step-time spike: step {s['step']} took "
                   f"{s['elapsed_s']:.3f}s vs median {s['median_s']:.3f}s "
-                  "(recompile? relay stall?)\n")
+                  "(recompile? device stall?)\n")
     if a["mem_growth"]:
         g = a["mem_growth"]
         out.write(f"  ANOMALY memory growth: {g['first_bytes']} -> "
@@ -772,15 +772,13 @@ def compare_runs(a: dict, b: dict) -> list:
     gb = (b.get("header") or {}).get("geometry")
     if (ga not in (None, "default")) or (gb not in (None, "default")):
         # The geometry A/B row (ISSUE 12): which arm compiled which
-        # certified kernel-geometry set — the benchwatch
-        # bench-zipf-geom / -geom-default readout.
+        # certified kernel-geometry set.
         text("geometry", ga or "default", gb or "default")
     da, db = a.get("data") or {}, b.get("data") or {}
     ca, cb = da.get("combiner"), db.get("combiner")
     if (ca and ca != "off") or (cb and cb != "off"):
         # The combiner A/B row (ISSUE 11): which arm ran which mode, and
-        # the net sort rows each deleted — the benchwatch
-        # bench-zipf-combiner / -nocombiner readout.
+        # the net sort rows each deleted.
         text("combiner", ca, cb)
         num("combiner_rows_deleted", da.get("combiner_rows_deleted"),
             db.get("combiner_rows_deleted"), "{:.0f}")

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Measure the >W-byte token envelope on the corpora we can generate.
 
-VERDICT r3 weak #4 / next #6: the pallas backend drops tokens longer than
+round-3 review weak #4 / next #6: the pallas backend drops tokens longer than
 its lookback window W (default 32) into ``dropped_*`` accounting while the
 XLA backend counts them exactly, so the size of the semantic gap between
 the backends on natural text was unknown.  This tool quantifies it host-side
@@ -13,7 +13,7 @@ Corpora: the two bench generators (synthetic-zipf, synthetic-natural), the
 bundled fixture ``test.txt``, and a "webby" proxy — natural text with ~0.3%
 of words replaced by URL/path/base64-ish long tokens, the enwik/WET
 statistic the other generators lack (real enwik8 is not mountable: zero
-egress).  Rates go into BENCHMARKS.md.
+egress).  Rates go into PERF.md.
 """
 
 from __future__ import annotations

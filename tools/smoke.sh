@@ -1,5 +1,5 @@
 #!/usr/bin/env bash
-# Fast iteration gate (VERDICT r5 #7): the <5-minute smoke subset — golden
+# Fast iteration gate (round-5 review #7): the <5-minute smoke subset — golden
 # semantics, CLI surface, table units, one pallas-interpret case, config
 # validation, the costcheck known-bad fixtures, and the ISSUE 6 fused-map
 # seam (stream-level fused-vs-split row-set identity, ngram bit-identity,
