@@ -1,8 +1,8 @@
 """Unified telemetry for the streaming runtime (ISSUE 2).
 
-One subsystem replacing three disconnected shims (``runtime/metrics.py``'s
-timers, ``runtime/logging.py``'s event lines, ``runtime/profiling.py``'s
-regions — all still used, now fed through one layer):
+One subsystem joining disconnected shims (``runtime/metrics.py``'s timers
+and ``runtime/logging.py``'s event lines, still used, now fed through one
+layer):
 
 * :mod:`.registry` — process-wide counters/gauges/histograms with labels;
 * :mod:`.ledger` — per-run JSONL step records (phase timings, bytes,

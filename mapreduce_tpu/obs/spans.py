@@ -4,11 +4,9 @@ ISSUE 2 tentpole (4): the executor's phase decomposition (``read_wait`` /
 ``stage`` / ``dispatch``) and the XProf timeline were previously separate
 worlds — the ledger said "dispatch took 8 s" and the profiler trace had no
 marker saying which 8 s that was.  A :func:`span` nests a
-``jax.profiler.TraceAnnotation`` (the same primitive as
-``runtime.profiling.region``) around the timed section AND accumulates the
-wall-clock into a :class:`...runtime.metrics.PhaseTimer` and/or a registry
-histogram, so ledger records and profiler timelines line up by
-construction.
+``jax.profiler.TraceAnnotation`` around the timed section AND accumulates
+the wall-clock into a :class:`...runtime.metrics.PhaseTimer`, so ledger
+records and profiler timelines line up by construction.
 
 Host-only: a TraceAnnotation is a nanosecond-scale TraceMe when no trace is
 active, and nothing here runs inside a jitted program.
@@ -18,36 +16,20 @@ from __future__ import annotations
 
 import contextlib
 import time
-from typing import Iterator, Optional
+from typing import Iterator
 
 
 @contextlib.contextmanager
-def span(name: str, timer=None, registry=None, metric: Optional[str] = None,
-         annotate: bool = True) -> Iterator[None]:
-    """Time a section as ``name``.
+def span(name: str, timer=None) -> Iterator[None]:
+    """Time a section as ``name``: the profiler-timeline label, and the
+    phase key in ``timer`` (a ``PhaseTimer``, optional)."""
+    import jax
 
-    Args:
-      name: phase key in ``timer`` and the profiler-timeline label.
-      timer: a ``PhaseTimer`` to accumulate into (optional).
-      registry: a ``MetricsRegistry`` for a histogram observation (optional).
-      metric: histogram name; defaults to ``"span." + name``.
-      annotate: emit the profiler TraceAnnotation (on by default; off when
-        a caller spans inside a tight host loop it never profiles).
-    """
-    ann = None
-    if annotate:
-        import jax
-
-        ann = jax.profiler.TraceAnnotation(name)
-        ann.__enter__()
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        if ann is not None:
-            ann.__exit__(None, None, None)
-        if timer is not None:
-            timer.phases[name] = timer.phases.get(name, 0.0) + dt
-        if registry is not None:
-            registry.observe(metric or f"span.{name}", dt)
+    with jax.profiler.TraceAnnotation(name):
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            if timer is not None:
+                timer.phases[name] = timer.phases.get(name, 0.0) \
+                    + time.perf_counter() - t0

@@ -23,6 +23,16 @@ reference parallelizes, lines->chunks, ``main.cu:113``), accumulators stay
 device-resident across steps (no per-step host round-trips, unlike the
 reference's per-call cudaMemcpy pattern), and ``finish`` runs the collective
 tree-merge + finalize once at the end.
+
+Program build: each program is acquired once per Engine, on first use, in
+JAX's ahead-of-time stages (trace, lower, compile-or-fetch from the
+persistent cache), each under its own span (``build.trace``,
+``build.lower``, ``build.compile`` inside ``build``), and counted in the
+process registry as ``engine.builds{program=...}``.  Every dispatch then
+calls the kept ``jax.stages.Compiled`` directly.  The programs carry stable
+names (``mr_step``, ``mr_step_many``, ``mr_finish``, ``mr_finish_residual``,
+``mr_partial``, ``mr_reset``, ``mr_replicate``): a profiler trace shows
+them as ``jit_<name>``.
 """
 
 from __future__ import annotations
@@ -36,6 +46,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from mapreduce_tpu import obs
 from mapreduce_tpu.parallel import collectives
 from mapreduce_tpu.parallel import mesh as mesh_mod
 
@@ -112,7 +123,8 @@ class Engine:
 
     def __init__(self, job: MapReduceJob, mesh: Mesh,
                  axis: str | tuple[str, ...] = "data",
-                 merge_strategy: str = "tree", data_stats: bool = False):
+                 merge_strategy: str = "tree", data_stats: bool = False,
+                 timer=None):
         axes = (axis,) if isinstance(axis, str) else tuple(axis)
         for a in axes:
             if a not in mesh.axis_names:
@@ -182,13 +194,14 @@ class Engine:
              in ("tree", "hier-tree-tree") else collectives.gather_merge))
         self._sharded = mesh_mod.sharded(mesh, axes if len(axes) > 1 else axes[0])
         self._replicated = mesh_mod.replicated(mesh)
-        self._step_fn = None
-        self._step_many_fns: dict[tuple[int, int], Any] = {}  # (K, repeats)
-        self._finish_fn = None
-        self._partial_fns: dict[bool, Any] = {}  # with_accum -> program
-        self._residual_fn = None
-        self._reset_fn = None
-        self._rep_fn = None
+        # The run's PhaseTimer (optional): program builds accumulate into
+        # its ``build`` / ``build.*`` phases.  ``build`` is present from the
+        # start, so a run that built nothing reads 0 s, not "not timed".
+        self.timer = timer
+        if timer is not None:
+            timer.phases.setdefault("build", 0.0)
+        # (program name, what varies) -> jax.stages.Compiled
+        self._programs: dict[tuple, Any] = {}
 
     def _device_index(self):
         """Linear index of this shard across all sharded axes (row-major)."""
@@ -232,17 +245,41 @@ class Engine:
         one jitted identity with replicated out_shardings (an all_gather
         over the mesh) makes every shard addressable on every process —
         the multi-host checkpoint fetch.  Costs one collective round."""
-        if self._rep_fn is None:
-            self._rep_fn = jax.jit(lambda s: s,
-                                   out_shardings=self._replicated)
-        return jax.tree.map(np.asarray, self._rep_fn(state))
+        def mr_replicate(s):
+            return s
+
+        rep = self._program(
+            ("mr_replicate",),
+            lambda: jax.jit(mr_replicate, out_shardings=self._replicated),
+            state)
+        return jax.tree.map(np.asarray, rep(state))
 
     # -- compiled programs ---------------------------------------------------
+
+    def _program(self, key: tuple, build: Callable[[], Any], *args) -> Any:
+        """The compiled program ``key`` (its name first), acquired on first
+        use from ``build()``, a ``jax.jit`` object, for arguments shaped
+        like ``args``: traced, lowered and compiled (or fetched from the
+        persistent compile cache) under the ``build`` span and its
+        ``build.trace`` / ``build.lower`` / ``build.compile`` children."""
+        prog = self._programs.get(key)
+        if prog is None:
+            timer = self.timer
+            with obs.span("build", timer):
+                with obs.span("build.trace", timer):
+                    traced = build().trace(*args)
+                with obs.span("build.lower", timer):
+                    lowered = traced.lower()
+                with obs.span("build.compile", timer):
+                    prog = lowered.compile()
+            obs.get_registry().counter("engine.builds", program=key[0]).inc()
+            self._programs[key] = prog
+        return prog
 
     def _build_step(self):
         axis, job, n = self.axis, self.job, self.n_devices
 
-        def local_step(state, chunks, step):
+        def mr_step(state, chunks, step):
             local = jax.tree.map(lambda x: x[0], state)
             chunk = chunks[0]
             dev = self._device_index()
@@ -259,7 +296,7 @@ class Engine:
             return jax.tree.map(lambda x: x[None], new)
 
         fn = shard_map(
-            local_step, mesh=self.mesh,
+            mr_step, mesh=self.mesh,
             in_specs=(P(axis), P(axis), P()),
             out_specs=(P(axis), P(axis)) if self.data_stats else P(axis),
             check_vma=False,
@@ -275,7 +312,7 @@ class Engine:
     def _build_step_many(self, k: int, repeats: int = 1):
         axis, job, n = self.axis, self.job, self.n_devices
 
-        def local_many(state, chunks, step0):
+        def mr_step_many(state, chunks, step0):
             local = jax.tree.map(lambda x: x[0], state)
             my = chunks[0]  # (k, chunk_bytes) after shard_map
             dev = self._device_index()
@@ -315,7 +352,7 @@ class Engine:
             return jax.tree.map(lambda x: x[None], new)
 
         fn = shard_map(
-            local_many, mesh=self.mesh,
+            mr_step_many, mesh=self.mesh,
             in_specs=(P(axis), P(axis), P()),
             out_specs=(P(axis), P(axis)) if self.data_stats else P(axis),
             check_vma=False,
@@ -351,12 +388,12 @@ class Engine:
     def _build_finish(self):
         axis, job = self.axis, self.job
 
-        def final(state):
+        def mr_finish(state):
             local = jax.tree.map(lambda x: x[0], state)
             return job.finalize(self._merge_local(local))
 
         fn = shard_map(
-            final, mesh=self.mesh,
+            mr_finish, mesh=self.mesh,
             in_specs=(P(axis),), out_specs=P(),
             check_vma=False,
         )
@@ -369,16 +406,14 @@ class Engine:
         when one exists.  Replicated output; nothing finalized."""
         axis = self.axis
 
-        def first(state):
-            local = jax.tree.map(lambda x: x[0], state)
-            return self._merge_local(local)
-
-        def fold(accum, state):
-            local = jax.tree.map(lambda x: x[0], state)
-            return self._fold_merged(self._merge_local(local), accum)
+        def mr_partial(*args):  # (state,), or (accum, state) with_accum
+            local = jax.tree.map(lambda x: x[0], args[-1])
+            merged = self._merge_local(local)
+            return self._fold_merged(merged, args[0]) if with_accum \
+                else merged
 
         fn = shard_map(
-            fold if with_accum else first, mesh=self.mesh,
+            mr_partial, mesh=self.mesh,
             in_specs=(P(), P(axis)) if with_accum else (P(axis),),
             out_specs=P(),
             check_vma=False,
@@ -394,13 +429,13 @@ class Engine:
         final collective record stays one span like the monolithic path."""
         axis, job = self.axis, self.job
 
-        def final(accum, state):
+        def mr_finish_residual(accum, state):
             local = jax.tree.map(lambda x: x[0], state)
             return job.finalize(
                 self._fold_merged(self._merge_local(local), accum))
 
         fn = shard_map(
-            final, mesh=self.mesh,
+            mr_finish_residual, mesh=self.mesh,
             in_specs=(P(), P(axis)), out_specs=P(),
             check_vma=False,
         )
@@ -415,13 +450,13 @@ class Engine:
         axis = self.axis
         fn_hook = getattr(job, "partial_reset", None)
 
-        def reset(state):
+        def mr_reset(state):
             local = jax.tree.map(lambda x: x[0], state)
             new = fn_hook(local) if fn_hook is not None else job.init_state()
             return jax.tree.map(lambda x: x[None], new)
 
         fn = shard_map(
-            reset, mesh=self.mesh,
+            mr_reset, mesh=self.mesh,
             in_specs=(P(axis),), out_specs=P(axis),
             check_vma=False,
         )
@@ -435,10 +470,10 @@ class Engine:
         With ``data_stats=True`` (construction-time) the return value is
         ``(new_state, DataStats)`` — the stats pytree's leaves are [D]
         uint32 scalars, non-donated, ready together with the state."""
-        if self._step_fn is None:
-            self._step_fn = self._build_step()
         chunks = jax.device_put(chunks, self._sharded)
-        return self._step_fn(state, chunks, jnp.uint32(step_index))
+        args = (state, chunks, jnp.uint32(step_index))
+        return self._program(("mr_step", chunks.shape), self._build_step,
+                             *args)(*args)
 
     def step_many(self, state: Any, chunks: jax.Array, step_index: int,
                   repeats: int = 1) -> Any:
@@ -458,17 +493,16 @@ class Engine:
         throughput measurements on high-latency links.
         """
         k = chunks.shape[1]
-        key = (k, repeats)
-        if key not in self._step_many_fns:
-            self._step_many_fns[key] = self._build_step_many(k, repeats)
         chunks = jax.device_put(chunks, self._sharded)
-        return self._step_many_fns[key](state, chunks, jnp.uint32(step_index))
+        args = (state, chunks, jnp.uint32(step_index))
+        return self._program(
+            ("mr_step_many", chunks.shape, repeats),
+            lambda: self._build_step_many(k, repeats), *args)(*args)
 
     def finish(self, state: Any) -> Any:
         """Collective global merge + finalize.  Result is replicated."""
-        if self._finish_fn is None:
-            self._finish_fn = self._build_finish()
-        return self._finish_fn(state)
+        return self._program(("mr_finish",), self._build_finish,
+                             state)(state)
 
     def partial_merge(self, accum: Any, state: Any) -> Any:
         """Window-boundary partial collective (ISSUE 20 leg 2): reduce
@@ -477,11 +511,17 @@ class Engine:
         window).  Returns the new replicated accumulator — dispatched
         async by the executor so the DCN transfer overlaps the next
         window's ingest."""
-        key = accum is not None
-        if key not in self._partial_fns:
-            self._partial_fns[key] = self._build_partial(key)
-        return self._partial_fns[key](accum, state) if key \
-            else self._partial_fns[key](state)
+        with_accum = accum is not None
+        args = (accum, state) if with_accum else (state,)
+        return self._program(("mr_partial", with_accum),
+                             lambda: self._build_partial(with_accum),
+                             *args)(*args)
+
+    def partial_shape(self) -> Any:
+        """Abstract shapes of the first partial merge's accumulator (the
+        checkpoint resume template), traced without building a program."""
+        return jax.eval_shape(
+            lambda: self._build_partial(False)(self.init_states()))
 
     def finish_residual(self, accum: Any, state: Any) -> Any:
         """Stream-end finish under overlap: merge the residual states,
@@ -489,17 +529,15 @@ class Engine:
         ever dispatched) this is exactly :meth:`finish`."""
         if accum is None:
             return self.finish(state)
-        if self._residual_fn is None:
-            self._residual_fn = self._build_residual()
-        return self._residual_fn(accum, state)
+        return self._program(("mr_finish_residual",), self._build_residual,
+                             accum, state)(accum, state)
 
     def partial_reset(self, state: Any) -> Any:
         """Fresh per-device states after a partial merge shipped the old
         ones (jobs with cross-step seam context override ``partial_reset``
         to keep it — NGram's carry)."""
-        if self._reset_fn is None:
-            self._reset_fn = self._build_reset()
-        return self._reset_fn(state)
+        return self._program(("mr_reset",), self._build_reset,
+                             state)(state)
 
     def run(self, batches, progress: Callable[[int], None] | None = None) -> Any:
         """Convenience: fold an iterable of [D, C] uint8 batches and finish."""

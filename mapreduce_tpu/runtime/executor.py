@@ -520,9 +520,7 @@ class _OverlapMerger:
     def accum_template(self):
         """Abstract accumulator shapes (checkpoint resume template): the
         first partial's output for this engine's strategy and job."""
-        eng = self.engine
-        return jax.eval_shape(lambda: eng.partial_merge(
-            None, eng.init_states()))
+        return self.engine.partial_shape()
 
 
 @dataclasses.dataclass
@@ -1720,8 +1718,11 @@ def run_job(job: MapReduceJob, path, config: Config = DEFAULT_CONFIG,
     from mapreduce_tpu.ops import datastats as datastats_ops
 
     data_stats = tel.enabled and datastats_ops.supports(job)
+    timer = metrics_mod.PhaseTimer()
+    timer.start("total")
     engine = Engine(job, mesh, axis=axes if len(axes) > 1 else axes[0],
-                    merge_strategy=merge_strategy, data_stats=data_stats)
+                    merge_strategy=merge_strategy, data_stats=data_stats,
+                    timer=timer)
     overlap = _OverlapMerger(engine, tel, lambda: True, plan, policy,
                              logger, merge_strategy,
                              config.inflight_groups) \
@@ -1729,9 +1730,6 @@ def run_job(job: MapReduceJob, path, config: Config = DEFAULT_CONFIG,
     data_agg = datastats_ops.DataAggregator.for_run(config, n_dev) \
         if data_stats else None
     range_lo, range_hi = byte_range if byte_range is not None else (0, None)
-
-    timer = metrics_mod.PhaseTimer()
-    timer.start("total")
 
     start_step, start_offset = 0, range_lo
     bases_list: list[np.ndarray] = []
@@ -1807,7 +1805,8 @@ def run_job(job: MapReduceJob, path, config: Config = DEFAULT_CONFIG,
         nonlocal job, engine
         job = _job_with_config(job, new_config)
         engine = Engine(job, mesh, axis=axes if len(axes) > 1 else axes[0],
-                        merge_strategy=merge_strategy, data_stats=data_stats)
+                        merge_strategy=merge_strategy, data_stats=data_stats,
+                        timer=timer)
         return engine
 
     hooks = _StreamHooks(
@@ -2013,8 +2012,10 @@ def run_job_global(job: MapReduceJob, path, config: Config = DEFAULT_CONFIG,
     # per-shard scalars, and fetching them on a mesh spanning other
     # processes' devices would need a collective round per retirement.
     # Data-plane telemetry is the per-host-driven / single-host story.
+    timer = metrics_mod.PhaseTimer()
+    timer.start("total")
     engine = Engine(job, mesh, axis=axes if len(axes) > 1 else axes[0],
-                    merge_strategy=merge_strategy)
+                    merge_strategy=merge_strategy, timer=timer)
     # Window-boundary overlap (ISSUE 20 leg 2) — THE fleet scenario: the
     # partial merge is one SPMD program every process dispatches at the
     # same deterministic boundary, so the DCN transfer of window N rides
@@ -2025,9 +2026,6 @@ def run_job_global(job: MapReduceJob, path, config: Config = DEFAULT_CONFIG,
                              config.inflight_groups) \
         if config.merge_overlap else None
     mine = np.asarray(dist.host_shards(n_dev), dtype=np.int64)
-
-    timer = metrics_mod.PhaseTimer()
-    timer.start("total")
 
     start_step, start_offset = 0, 0
     bases_list: list[np.ndarray] = []
@@ -2311,8 +2309,9 @@ def count_file(path, config: Config = DEFAULT_CONFIG, mesh=None,
             int(value.kmv_n_valid), int(value.kmv_kth_hi),
             int(value.kmv_kth_lo), config.table_capacity)
         value = value.table
-    result = recover_from_file(value, path, rr.bases, n_dev, ngram=ngram,
-                               estimate_distinct=not top_k)
+    with obs.span("recover"):
+        result = recover_from_file(value, path, rr.bases, n_dev, ngram=ngram,
+                                   estimate_distinct=not top_k)
     if kmv_est is not None:
         result = dataclasses.replace(
             result, distinct=max(len(result.words), int(round(kmv_est))))

@@ -1,15 +1,15 @@
-"""Profiler hooks: XProf/Perfetto traces and named trace regions.
+"""Profiler hooks: XProf/Perfetto traces and the compile cache's place.
 
 The reference has no tracing of any kind (``time.h`` is a dead include,
 ``main.cu:6``; SURVEY §5).  Here any run can capture a ``jax.profiler`` trace
 — device timelines, XLA op breakdown, HBM usage — viewable in XProf /
-Perfetto, plus cheap named host regions that show up on the same timeline.
+Perfetto; the program's own named host spans (``obs.span``) show up on the
+same timeline.
 
 Usage::
 
     with profiling.trace("/tmp/trace"):     # no-op when path is falsy
-        with profiling.region("step"):
-            state = engine.step(state, batch.data, batch.step)
+        state = engine.step(state, batch.data, batch.step)
 """
 
 from __future__ import annotations
@@ -29,15 +29,6 @@ def trace(path: Optional[str]) -> Iterator[None]:
     import jax
 
     with jax.profiler.trace(path):
-        yield
-
-
-@contextlib.contextmanager
-def region(name: str) -> Iterator[None]:
-    """A named region on the profiler timeline (cheap when not tracing)."""
-    import jax
-
-    with jax.profiler.TraceAnnotation(name):
         yield
 
 

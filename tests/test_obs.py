@@ -6,6 +6,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -159,14 +160,20 @@ def test_flight_summarize_state_bounds_leaves():
 # -- spans ------------------------------------------------------------------
 
 def test_span_accumulates_timer_and_registry():
-    reg = MetricsRegistry()
+    """Spans accumulate into the timer only: repeated spans of one name
+    add up, and nothing lands in the process registry."""
     timer = metrics_mod.PhaseTimer()
-    with obs.span("work", timer, registry=reg):
-        pass
+    with obs.span("work", timer):
+        time.sleep(0.002)
+    first = timer["work"]
     with obs.span("work", timer):
         pass
-    assert timer["work"] > 0
-    assert reg.snapshot()["histograms"]["span.work"]["count"] == 1
+    with obs.span("untimed"):
+        pass
+    assert 0.002 <= first < timer["work"]
+    assert set(timer.phases) == {"work"}
+    assert not any(name.startswith("span.") for name in
+                   obs.get_registry().snapshot()["histograms"])
 
 
 def test_span_records_on_exception():
