@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from mapreduce_tpu import constants
-from mapreduce_tpu.config import Config, DEFAULT_CONFIG
+from mapreduce_tpu.config import Config, DEFAULT_CONFIG, segmin_allowed
 from mapreduce_tpu.ops import datastats
 from mapreduce_tpu.ops import sketch as sketch_ops
 from mapreduce_tpu.ops import table as table_ops
@@ -762,6 +762,16 @@ class WordCountJob:
         # cadence-independent.
         return "wordcount"
 
+    def program_key(self):
+        """Everything the hooks read when traced: the job's type, the whole
+        Config and the sizes taken from it (``identity()`` leaves out the
+        knobs that change the program but not the result).  The segmin
+        refusal reads the environment at trace time, so its override is
+        part of the key."""
+        return (type(self), self.config, self.capacity, self.batch_capacity,
+                self.merge_every,
+                self.config.sort_mode == "segmin" and segmin_allowed())
+
 
 class TopKTable(NamedTuple):
     """A top-k finalized table plus the pre-reorder KMV snapshot.
@@ -803,6 +813,9 @@ class TopKWordCountJob(WordCountJob):
         # k only affects finalize, but including it keeps resume semantics
         # obvious: one checkpoint, one job description.
         return f"wordcount-top{self.k}"
+
+    def program_key(self):
+        return super().program_key() + (self.k,)
 
 
 class NGramState(NamedTuple):
@@ -1025,6 +1038,9 @@ class NGramCountJob(WordCountJob):
         # would mix gram orders: n is part of the job identity.
         return f"ngram{self.n}" + (f"-top{self.k}" if self.k else "")
 
+    def program_key(self):
+        return super().program_key() + (self.n, self.k)
+
 
 class SketchedState(NamedTuple):
     """Count table + HyperLogLog registers (a pytree; engine/collective
@@ -1082,6 +1098,8 @@ class _SketchComposedJob:
     """
 
     state_cls: type
+    #: The subclass's sketch parameters, by attribute name (program_key).
+    sketch_params: tuple[str, ...] = ()
 
     def __init__(self, base: WordCountJob):
         self.base = base
@@ -1243,6 +1261,13 @@ class _SketchComposedJob:
         # cadence-independent.
         return f"{type(self).__name__.lower()}({self.base.identity()})"
 
+    def program_key(self):
+        base = self.base.program_key()
+        if base is None:
+            return None
+        return (type(self), base, self.flush_every) \
+            + tuple(getattr(self, a) for a in self.sketch_params)
+
 
 class FreqSketchedWordCountJob(_SketchComposedJob):
     """Wrap any WordCount-family job with a Count-Min frequency sketch.
@@ -1256,6 +1281,7 @@ class FreqSketchedWordCountJob(_SketchComposedJob):
     """
 
     state_cls = FreqSketchedState
+    sketch_params = ("depth", "width_log2")
 
     def __init__(self, base: WordCountJob, depth: int = sketch_ops.CMS_DEPTH,
                  width_log2: int = sketch_ops.CMS_WIDTH_LOG2):
@@ -1285,6 +1311,7 @@ class SketchedWordCountJob(_SketchComposedJob):
     """
 
     state_cls = SketchedState
+    sketch_params = ("precision",)
 
     def __init__(self, base: WordCountJob, precision: int = sketch_ops.DEFAULT_PRECISION):
         super().__init__(base)

@@ -24,20 +24,28 @@ device-resident across steps (no per-step host round-trips, unlike the
 reference's per-call cudaMemcpy pattern), and ``finish`` runs the collective
 tree-merge + finalize once at the end.
 
-Program build: each program is acquired once per Engine, on first use, in
-JAX's ahead-of-time stages (trace, lower, compile-or-fetch from the
-persistent cache), each under its own span (``build.trace``,
-``build.lower``, ``build.compile`` inside ``build``), and counted in the
-process registry as ``engine.builds{program=...}``.  Every dispatch then
-calls the kept ``jax.stages.Compiled`` directly.  The programs carry stable
-names (``mr_step``, ``mr_step_many``, ``mr_finish``, ``mr_finish_residual``,
-``mr_partial``, ``mr_reset``, ``mr_replicate``): a profiler trace shows
-them as ``jit_<name>``.
+Program build: each program is acquired once per Engine, on first use.
+It comes from the process-wide program table when an Engine with the same
+key built it before (:data:`PROGRAM_TABLE_SIZE` programs, least recently
+used evicted; counted as ``engine.reuses{program=...}``).  Otherwise it is
+built in JAX's ahead-of-time stages (trace, lower, compile-or-fetch from
+the persistent cache), each under its own span (``build.trace``,
+``build.lower``, ``build.compile`` inside ``build``), and counted as
+``engine.builds{program=...}``.  The key is what the trace reads: the job's
+``program_key()``, the mesh, axes, merge strategy and stats mode, the
+program's own key, and the arguments' structure, shapes, dtypes and
+shardings.  A job whose ``program_key()`` is None keeps its programs to
+its Engine.  Every dispatch then calls the kept ``jax.stages.Compiled``
+directly.  The programs carry stable names (``mr_step``, ``mr_step_many``,
+``mr_finish``, ``mr_finish_residual``, ``mr_partial``, ``mr_reset``,
+``mr_replicate``): a profiler trace shows them as ``jit_<name>``.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
+import threading
 from typing import Any, Callable
 
 import jax
@@ -49,6 +57,46 @@ from jax.sharding import Mesh, PartitionSpec as P
 from mapreduce_tpu import obs
 from mapreduce_tpu.parallel import collectives
 from mapreduce_tpu.parallel import mesh as mesh_mod
+
+#: Programs the process-wide table holds before it evicts the least
+#: recently used.
+PROGRAM_TABLE_SIZE = 64
+
+# (engine key, program key, argument signature) -> jax.stages.Compiled,
+# least recently used first.  It holds nothing that refers back to an
+# Engine or a job: a Compiled keeps the executable, not the traced function.
+_PROGRAMS: collections.OrderedDict = collections.OrderedDict()
+_PROGRAMS_LOCK = threading.Lock()
+
+
+def clear_programs() -> None:
+    """Empty the process-wide program table: every Engine builds anew."""
+    with _PROGRAMS_LOCK:
+        _PROGRAMS.clear()
+
+
+def _table_get(key: tuple) -> Any:
+    with _PROGRAMS_LOCK:
+        prog = _PROGRAMS.get(key)
+        if prog is not None:
+            _PROGRAMS.move_to_end(key)
+        return prog
+
+
+def _table_put(key: tuple, prog: Any) -> None:
+    with _PROGRAMS_LOCK:
+        _PROGRAMS[key] = prog
+        _PROGRAMS.move_to_end(key)
+        while len(_PROGRAMS) > PROGRAM_TABLE_SIZE:
+            _PROGRAMS.popitem(last=False)
+
+
+def _signature(args) -> tuple:
+    """What a trace reads of its arguments: the tree structure and each
+    leaf's shape, dtype, weak type and sharding."""
+    leaves, tree = jax.tree.flatten(args)
+    return tree, tuple((x.shape, x.dtype, getattr(x, "weak_type", False),
+                        getattr(x, "sharding", None)) for x in leaves)
 
 
 def _map_with_axis(job, chunk, chunk_id, axis, device_index):
@@ -107,6 +155,13 @@ class MapReduceJob:
         something else).  Subclasses with parameters that change the
         meaning of accumulated state must include them."""
         return type(self).__name__.lower()
+
+    def program_key(self) -> Any:
+        """A hashable value covering everything the hooks read when traced
+        (the job's type, its parameters, its whole Config), so that Engines
+        of equal keys share compiled programs; None (the default) keeps a
+        job's programs to its own Engine."""
+        return None
 
 
 class Engine:
@@ -200,7 +255,13 @@ class Engine:
         self.timer = timer
         if timer is not None:
             timer.phases.setdefault("build", 0.0)
-        # (program name, what varies) -> jax.stages.Compiled
+        # What the trace reads besides a program's own key and arguments;
+        # None keeps this Engine's programs out of the process-wide table.
+        # Jobs are duck-typed, so the hook is optional.
+        job_key = getattr(job, "program_key", lambda: None)()
+        self._share_key = None if job_key is None else (
+            job_key, mesh, axes, merge_strategy, self.data_stats)
+        # (program name, what varies) -> jax.stages.Compiled, as acquired
         self._programs: dict[tuple, Any] = {}
 
     def _device_index(self):
@@ -258,12 +319,21 @@ class Engine:
 
     def _program(self, key: tuple, build: Callable[[], Any], *args) -> Any:
         """The compiled program ``key`` (its name first), acquired on first
-        use from ``build()``, a ``jax.jit`` object, for arguments shaped
-        like ``args``: traced, lowered and compiled (or fetched from the
-        persistent compile cache) under the ``build`` span and its
-        ``build.trace`` / ``build.lower`` / ``build.compile`` children."""
+        use for arguments shaped like ``args``: from the process-wide table
+        when another Engine of the same key built it, else from
+        ``build()``, a ``jax.jit`` object, traced, lowered and compiled (or
+        fetched from the persistent compile cache) under the ``build`` span
+        and its ``build.trace`` / ``build.lower`` / ``build.compile``
+        children."""
         prog = self._programs.get(key)
-        if prog is None:
+        if prog is not None:
+            return prog
+        shared = None if self._share_key is None \
+            else (self._share_key, key, _signature(args))
+        prog = None if shared is None else _table_get(shared)
+        if prog is not None:
+            obs.get_registry().counter("engine.reuses", program=key[0]).inc()
+        else:
             timer = self.timer
             with obs.span("build", timer):
                 with obs.span("build.trace", timer):
@@ -273,7 +343,9 @@ class Engine:
                 with obs.span("build.compile", timer):
                     prog = lowered.compile()
             obs.get_registry().counter("engine.builds", program=key[0]).inc()
-            self._programs[key] = prog
+            if shared is not None:
+                _table_put(shared, prog)
+        self._programs[key] = prog
         return prog
 
     def _build_step(self):

@@ -43,6 +43,17 @@ def _clear_jax_caches_between_modules():
     jax.clear_caches()
 
 
+@pytest.fixture(autouse=True)
+def _fresh_program_table():
+    """Every test starts from an empty process-wide program table
+    (parallel/mapreduce.py), so whether an Engine builds does not depend on
+    which test ran before it in the worker, and a program traced under one
+    test's monkeypatched modules never serves another test."""
+    from mapreduce_tpu.parallel.mapreduce import clear_programs
+
+    clear_programs()
+
+
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(20260729)

@@ -1,7 +1,9 @@
 """Program build and string recovery as spans (``obs.span``) inside the
 program: one profiled ``count_file`` on the CPU, reduced by the benchmark's
 own trace reduction (``benchmark/trace.py``) with the build spans added to
-the host spans it keeps; and the Engine's build-once contract."""
+the host spans it keeps; and the Engine's build-once contract.  Each test
+starts from an empty program table (``tests/conftest.py``), so a fresh
+Engine builds."""
 
 from __future__ import annotations
 
@@ -18,7 +20,7 @@ import pytest
 from mapreduce_tpu import obs
 from mapreduce_tpu.config import Config
 from mapreduce_tpu.models.wordcount import WordCountJob
-from mapreduce_tpu.parallel.mapreduce import Engine
+from mapreduce_tpu.parallel.mapreduce import Engine, clear_programs
 from mapreduce_tpu.parallel.mesh import data_mesh
 from mapreduce_tpu.runtime import executor
 from mapreduce_tpu.runtime import metrics as metrics_mod
@@ -61,7 +63,9 @@ class _Phases(logging.Handler):
 @pytest.fixture(scope="module")
 def profiled(tmp_path_factory, rng):
     """One ``count_file`` job of three steps under the profiler, inside a
-    ``job`` span as the benchmark harness opens it."""
+    ``job`` span as the benchmark harness opens it, from an empty program
+    table (a module fixture runs before the per-test clear)."""
+    clear_programs()
     tmp = tmp_path_factory.mktemp("build_spans")
     corpus = make_corpus(rng, 250, 60)
     path = tmp / "corpus.txt"
