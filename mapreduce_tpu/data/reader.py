@@ -18,6 +18,7 @@ reference would overflow a stack buffer in that case (``main.cu:184,199``).
 from __future__ import annotations
 
 import dataclasses
+import mmap
 import os
 from typing import Iterator
 
@@ -164,28 +165,10 @@ def iter_batches_multi(paths, n_shards: int, chunk_bytes: int,
 
 def read_words_at_multi(paths, spans: list[tuple[int, int]]) -> list[bytes]:
     """Multi-file :func:`read_words_at`: spans use virtual corpus offsets."""
-    if isinstance(paths, (str, bytes, os.PathLike)):
-        return read_words_at(paths, spans)
     if not spans:
         return []
-    starts = np.cumsum([0] + [_file_size(p) for p in paths])
-    offs = np.asarray([s[0] for s in spans], dtype=np.int64)
-    file_idx = np.searchsorted(starts, offs, side="right") - 1
-    # Group spans by file with one argsort (not a per-file rescan).
-    order = np.argsort(file_idx, kind="stable")
-    out: list[bytes | None] = [None] * len(spans)
-    i = 0
-    while i < len(order):
-        k = int(file_idx[order[i]])
-        j = i
-        while j < len(order) and file_idx[order[j]] == k:
-            j += 1
-        group = order[i:j]
-        local = [(int(offs[g] - starts[k]), spans[g][1]) for g in group]
-        for g, word in zip(group, read_words_at(paths[k], local)):
-            out[g] = word
-        i = j
-    return out  # type: ignore[return-value]
+    offs, lens = np.asarray(spans, dtype=np.int64).T
+    return read_spans(paths, offs, lens)
 
 
 def prefetch(batches: Iterator[Batch], depth: int = 2) -> Iterator[Batch]:
@@ -269,10 +252,57 @@ def _file_size(path: str) -> int:
 
 def read_words_at(path: str, spans: list[tuple[int, int]]) -> list[bytes]:
     """Host-side string recovery: exact bytes for (absolute_offset, length)."""
-    if not spans:
+    return read_words_at_multi(path, spans)
+
+
+def slice_spans(buf, offsets: np.ndarray, lengths: np.ndarray) -> list[bytes]:
+    """``bytes(buf[o:o + n])`` for each span, in order.  ``buf`` is anything
+    whose slice is ``bytes`` (``bytes``, ``mmap.mmap``); the bounds go to
+    Python ints in two ``tolist`` calls, so no numpy object is made per span.
+    Python slice semantics: a span past the end is clipped there."""
+    starts = np.asarray(offsets, dtype=np.int64)
+    ends = starts + np.asarray(lengths, dtype=np.int64)
+    return [buf[a:b] for a, b in zip(starts.tolist(), ends.tolist())]
+
+
+def read_spans(paths, offsets: np.ndarray,
+               lengths: np.ndarray) -> list[bytes]:
+    """Exact bytes of each ``(offsets[i], lengths[i])`` span, in input order.
+
+    ``paths`` is one file or a list read as one virtual corpus (offsets run
+    on across files).  A span is read from the file its offset falls in and
+    is clipped at that file's end.  Spans are grouped by file with one
+    argsort, and each touched file is mapped once; a zero-byte file is never
+    mapped (``mmap`` refuses one): its spans are empty.
+    """
+    offs = np.asarray(offsets, dtype=np.int64)
+    lens = np.asarray(lengths, dtype=np.int64)
+    if offs.shape[0] == 0:
         return []
-    mm = np.memmap(path, dtype=np.uint8, mode="r")
-    return [bytes(mm[off: off + ln]) for off, ln in spans]
+    plist = [paths] if isinstance(paths, (str, bytes, os.PathLike)) \
+        else list(paths)
+    sizes = [_file_size(p) for p in plist]
+    starts = np.cumsum([0] + sizes)
+    file_idx = np.clip(np.searchsorted(starts, offs, side="right") - 1,
+                       0, len(plist) - 1)
+    order = np.argsort(file_idx, kind="stable")
+    cuts = np.flatnonzero(np.diff(file_idx[order])) + 1
+    words: list[bytes] = []
+    for group in np.split(order, cuts):
+        k = int(file_idx[group[0]])
+        local = offs[group] - starts[k]
+        if sizes[k] == 0:
+            words.extend([b""] * len(group))
+            continue
+        with open(plist[k], "rb") as f, \
+                mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ) as mm:
+            words.extend(slice_spans(mm, local, lens[group]))
+    if np.any(np.diff(order) < 0):
+        # Spans were given out of file order: put them back in input order.
+        inverse = np.empty_like(order)
+        inverse[order] = np.arange(len(order))
+        words = [words[i] for i in inverse.tolist()]
+    return words
 
 
 def scan_gram_lengths_bytes(source: bytes | np.ndarray, offsets,

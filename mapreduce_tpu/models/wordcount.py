@@ -24,6 +24,7 @@ import numpy as np
 
 from mapreduce_tpu import constants
 from mapreduce_tpu.config import Config, DEFAULT_CONFIG, segmin_allowed
+from mapreduce_tpu.data import reader as reader_mod
 from mapreduce_tpu.ops import datastats
 from mapreduce_tpu.ops import sketch as sketch_ops
 from mapreduce_tpu.ops import table as table_ops
@@ -530,16 +531,15 @@ def recover_result(tbl: table_ops.CountTable, source: bytes,
     cnt = (count + (count_hi << np.int64(32)))[valid]
     seam = np.flatnonzero(length == int(constants.SEAM_GRAM_LENGTH))
     if len(seam):
-        from mapreduce_tpu.data import reader as reader_mod
-
         length[seam] = reader_mod.scan_gram_lengths_bytes(
             source, pos[seam].astype(np.int64), ngram)
     order = np.argsort(pos, kind="stable")
-    words = [bytes(source[int(p): int(p) + int(l)]) for p, l in zip(pos[order], length[order])]
+    buf = source if isinstance(source, bytes) else bytes(source)
+    words = reader_mod.slice_spans(buf, pos[order], length[order])
     dropped_uniques, dropped_count = tbl.dropped_totals()
     return WordCountResult(
         words=words,
-        counts=[int(c) for c in cnt[order]],
+        counts=cnt[order].tolist(),
         total=int(np.asarray(tbl.total_count())),
         distinct=_reported_distinct(tbl, len(words), dropped_uniques,
                                     estimate_distinct),

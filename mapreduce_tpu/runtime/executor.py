@@ -2244,12 +2244,14 @@ def recover_from_file(tbl: table_ops.CountTable, path, bases: np.ndarray,
         length[seam] = reader_mod.scan_gram_lengths(
             path, absolute[seam], ngram, cut_offsets=bases.ravel())
     order = np.argsort(absolute, kind="stable")
-    spans = [(int(absolute[i]), int(length[i])) for i in order]
-    words = reader_mod.read_words_at_multi(path, spans)
+    words = reader_mod.read_spans(path, absolute[order], length[order])
+    registry = obs.get_registry()
+    registry.counter("recover.entries").inc(len(words))
+    registry.counter("recover.bytes").inc(sum(map(len, words)))
     dropped_uniques, dropped_count = tbl.dropped_totals()
     return WordCountResult(
         words=words,
-        counts=[int(c) for c in cnt[order]],
+        counts=cnt[order].tolist(),
         total=int(np.asarray(tbl.total_count())),
         distinct=_reported_distinct(tbl, len(words), dropped_uniques,
                                     estimate_distinct),

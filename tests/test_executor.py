@@ -5,8 +5,10 @@ import os
 import numpy as np
 import pytest
 
+from mapreduce_tpu import obs
 from mapreduce_tpu.config import Config
 from mapreduce_tpu.models.wordcount import WordCountJob
+from mapreduce_tpu.ops import table as table_ops
 from mapreduce_tpu.parallel.mesh import data_mesh
 from mapreduce_tpu.runtime import checkpoint as ckpt
 from mapreduce_tpu.runtime import executor
@@ -789,3 +791,93 @@ def test_window_ab_identical_across_families(tmp_path, rng):
     n4 = executor.count_file(path, piped, mesh=data_mesh(2), ngram=2)
     assert n1.as_dict() == n4.as_dict()
     assert n1.words == n4.words and n1.total == n4.total
+
+
+def _random_table(rng, n_chunks: int, chunk: int, capacity: int = 4096):
+    """A CountTable of numpy leaves: about half the slots live, spans of
+    1-12 bytes inside ``n_chunks`` chunks of ``chunk`` bytes, one count
+    past 2**32."""
+    live = rng.random(capacity) < 0.5
+    count = np.where(live, rng.integers(1, 1 << 20, capacity), 0)
+    count_hi = np.zeros(capacity, np.int64)
+    first = int(np.flatnonzero(live)[0])
+    count_hi[first] = 3  # 3 * 2**32 + count: past 32 bits
+    u32 = lambda a: np.asarray(a, dtype=np.uint32)  # noqa: E731
+    return table_ops.CountTable(
+        key_hi=u32(rng.integers(0, 1 << 32, capacity)),
+        key_lo=u32(rng.integers(0, 1 << 32, capacity)),
+        count=u32(count), count_hi=u32(count_hi),
+        pos_hi=u32(rng.integers(0, n_chunks, capacity)),
+        pos_lo=u32(rng.integers(0, chunk - 12, capacity)),
+        length=u32(rng.integers(1, 13, capacity)),
+        dropped_uniques=np.uint32(7), dropped_count=np.uint32(11),
+        dropped_uniques_hi=np.uint32(0), dropped_count_hi=np.uint32(1))
+
+
+def _per_key_recover(tbl, paths, bases, n_devices):
+    """Recovery as it was before array spans, one numpy scalar and one
+    ``np.memmap`` slice per key (reference; no seam entries)."""
+    count = np.asarray(tbl.count).astype(np.int64)
+    count_hi = np.asarray(tbl.count_hi).astype(np.int64)
+    valid = (count > 0) | (count_hi > 0)
+    chunk_id = np.asarray(tbl.pos_hi)[valid].astype(np.int64)
+    pos = np.asarray(tbl.pos_lo)[valid].astype(np.int64)
+    length = np.asarray(tbl.length)[valid].astype(np.int64)
+    cnt = (count + (count_hi << np.int64(32)))[valid]
+    absolute = executor.absolute_offsets(chunk_id, pos, bases, n_devices)
+    order = np.argsort(absolute, kind="stable")
+    starts = np.cumsum([0] + [os.path.getsize(p) for p in paths])
+    words = []
+    for i in order:
+        off, ln = int(absolute[i]), int(length[i])
+        k = int(np.searchsorted(starts, off, side="right")) - 1
+        mm = np.memmap(paths[k], dtype=np.uint8, mode="r")
+        words.append(bytes(mm[off - int(starts[k]): off - int(starts[k]) + ln]))
+    return words, [int(c) for c in cnt[order]]
+
+
+@pytest.mark.parametrize("n_files", [1, 3])
+def test_recover_from_file_matches_per_key_reference(tmp_path, rng, n_files):
+    n_devices, steps, chunk = 2, 3, 1000
+    size = n_devices * steps * chunk // n_files
+    paths = []
+    for i in range(n_files):
+        p = tmp_path / f"part{i}.txt"
+        p.write_bytes(rng.integers(97, 123, size, dtype=np.uint8).tobytes())
+        paths.append(str(p))
+    bases = (np.arange(steps * n_devices, dtype=np.int64) * chunk).reshape(
+        steps, n_devices)
+    tbl = _random_table(rng, steps * n_devices, chunk)
+    path = paths[0] if n_files == 1 else paths
+    result = executor.recover_from_file(tbl, path, bases, n_devices,
+                                        estimate_distinct=False)
+    words, counts = _per_key_recover(tbl, paths, bases, n_devices)
+    assert result.words == words
+    assert result.counts == counts
+    assert result.total == sum(counts) + 11 + (1 << 32)
+    assert result.distinct == len(words) + 7
+    assert (result.dropped_uniques, result.dropped_count) == (7, 11 + (1 << 32))
+
+
+def test_recover_from_file_types_and_counters(tmp_path, rng):
+    """Words are ``bytes`` (no mmap, memoryview or array), counts Python
+    ints exact past 2**32; ``recover.entries`` / ``recover.bytes`` count
+    the keys and bytes of the call."""
+    chunk = 1000
+    path = _write(tmp_path, rng.integers(97, 123, 2 * chunk,
+                                         dtype=np.uint8).tobytes())
+    tbl = _random_table(rng, 2, chunk)
+    bases = np.array([[0, chunk]], dtype=np.int64)
+
+    def counters():
+        c = obs.get_registry().snapshot()["counters"]
+        return c.get("recover.entries", 0), c.get("recover.bytes", 0)
+
+    before = counters()
+    result = executor.recover_from_file(tbl, path, bases, 2)
+    after = counters()
+    assert all(type(w) is bytes for w in result.words)
+    assert all(type(c) is int for c in result.counts)
+    assert max(result.counts) > 1 << 32
+    assert after[0] - before[0] == len(result.words) > 0
+    assert after[1] - before[1] == sum(map(len, result.words))

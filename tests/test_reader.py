@@ -181,3 +181,60 @@ def test_multi_file_start_and_end_offsets(tmp_path):
         for row, ln in zip(b.data, b.lengths):
             words += bytes(row[: int(ln)]).split()
     assert words == [b"bb", b"cc"]
+
+
+def _memmap_slices(paths, spans):
+    """The per-span reading that :func:`reader.read_spans` replaced: one
+    ``np.memmap`` slice copied to ``bytes`` per span (reference)."""
+    starts = np.cumsum([0] + [len(open(p, "rb").read()) for p in paths])
+    out = []
+    for off, ln in spans:
+        k = int(np.searchsorted(starts, off, side="right")) - 1
+        mm = np.memmap(paths[k], dtype=np.uint8, mode="r")
+        local = off - int(starts[k])
+        out.append(bytes(mm[local: local + ln]))
+    return out
+
+
+_SPAN_CASES = {
+    "one_file": ([b"alpha beta gamma\n"], [(0, 5), (6, 4), (11, 5)]),
+    "three_files_out_of_order": (
+        [b"alpha beta\n", b"gamma delta\n", b"eps zeta\n"],
+        [(28, 4), (0, 5), (17, 5), (23, 3), (6, 4), (11, 5)]),
+    "zero_byte_file_between": (
+        [b"alpha beta\n", b"", b"gamma\n"], [(11, 5), (0, 5), (6, 4)]),
+    "clipped_at_eof": ([b"alpha beta\n", b"gamma"], [(6, 40), (11, 9)]),
+    "empty_span_list": ([b"alpha\n"], []),
+    "zero_length_span": ([b"alpha beta\n", b"gamma\n"], [(6, 0), (11, 0)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SPAN_CASES))
+def test_read_spans_matches_memmap_slicing(tmp_path, case):
+    blobs, spans = _SPAN_CASES[case]
+    paths = _write_files(tmp_path, blobs)
+    want = _memmap_slices(paths, spans)
+    offs = np.asarray([s[0] for s in spans], dtype=np.int64)
+    lens = np.asarray([s[1] for s in spans], dtype=np.int64)
+    got = reader.read_spans(paths, offs, lens)
+    assert got == want
+    assert all(type(w) is bytes for w in got)
+    assert reader.read_words_at_multi(paths, spans) == want
+    if len(paths) == 1:
+        assert reader.read_words_at(paths[0], spans) == want
+
+
+def test_read_spans_zero_byte_file_alone(tmp_path):
+    """A zero-byte file is never mapped (``mmap`` refuses one): its spans
+    read empty."""
+    path = _write(tmp_path, b"")
+    assert reader.read_spans(path, np.array([0, 0]), np.array([3, 0])) == \
+        [b"", b""]
+    assert reader.read_words_at(path, [(0, 4)]) == [b""]
+
+
+def test_slice_spans_returns_bytes_of_a_buffer():
+    got = reader.slice_spans(b"alpha beta", np.array([6, 0, 8]),
+                             np.array([4, 5, 9]))
+    assert got == [b"beta", b"alpha", b"ta"]
+    assert all(type(w) is bytes for w in got)
