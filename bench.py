@@ -26,7 +26,7 @@ step size, default 32 — the measured sweet spot on v5e), BENCH_REPEATS
 (device passes over the resident corpus in the timed dispatch, default 8),
 BENCH_SUPERSTEP (override chunks per dispatch; default: all resident),
 BENCH_BASELINE_MB (CPU baseline slice, default 16), BENCH_SORT_MODE /
-BENCH_SORT_IMPL / BENCH_MAP_IMPL / BENCH_COMBINER / BENCH_GEOMETRY /
+BENCH_MAP_IMPL / BENCH_COMBINER / BENCH_GEOMETRY /
 BENCH_MERGE_EVERY /
 BENCH_MERGE_STRATEGY (tree / gather / keyrange / hier-kr-tree /
 hier-tree-tree / auto — the reduction seam the static planner
@@ -300,11 +300,9 @@ def main() -> int:
 
     # Capacities sized to the corpus: 50K-word Zipf vocab fits comfortably in
     # a 256K-slot table and 64K distinct-per-chunk batch extraction.
-    # BENCH_SORT_MODE switches the aggregation sort strategy (sort3/segmin,
+    # BENCH_SORT_MODE switches the aggregation sort strategy (sort3/stable2,
     # bit-identical results) and BENCH_MERGE_EVERY the table-merge cadence,
     # so live windows can A/B the sort floor and the merge amortization.
-    # BENCH_SORT_IMPL A/Bs the Pallas radix partition/sort against the XLA
-    # sort floor (the round-6 pricing note; bit-identical results).
     # BENCH_COMBINER A/Bs the ISSUE 11 map-side combiner (hot-cache /
     # salt; pairs with BENCH_MAP_IMPL=fused).  BENCH_GEOMETRY A/Bs a
     # searched kernel-geometry set (ISSUE 12): a preset name, or a JSON
@@ -338,8 +336,6 @@ def main() -> int:
                  batch_unique_capacity=1 << 16,
                  sort_mode=os.environ.get("BENCH_SORT_MODE",
                                           Config.sort_mode),
-                 sort_impl=os.environ.get("BENCH_SORT_IMPL",
-                                          Config.sort_impl),
                  map_impl=os.environ.get("BENCH_MAP_IMPL",
                                          Config.map_impl),
                  combiner=os.environ.get("BENCH_COMBINER",

@@ -7,10 +7,9 @@ searchable, in the spirit of CUDA-LLM (PAPERS.md: search over kernel
 variants with a correctness gate as fitness):
 
 1. :func:`enumerate_candidates` walks the candidate lattice — window
-   heights, slot budgets, combiner cache depths, seam-aux heights, radix
-   digit widths / slab slacks, each axis stepped on the (8, 128)/(32, 128)
-   tile grids the :class:`~mapreduce_tpu.config.Geometry` validation
-   encodes;
+   heights, slot budgets, combiner cache depths and seam-aux heights,
+   each axis stepped on the (8, 128)/(32, 128) tile grids the
+   :class:`~mapreduce_tpu.config.Geometry` validation encodes;
 2. every candidate is **certified statically** (:func:`certify`): its
    full kernel-plan set (``ops/pallas/meta.geometry_plans`` — the SAME
    constructor that derives the shipped ``production_plans``) must fit
@@ -20,8 +19,8 @@ variants with a correctness gate as fitness):
    hbm-cost model's own arithmetic: :func:`stable2_sort_rows` (the
    canonical formula — ``analysis/costmodel.py`` imports it from here)
    re-derived from the CANDIDATE geometry instead of the shipped
-   constant, the sort's one-pass bytes, the radix slab write
-   amplification, and the measured-density spill headroom;
+   constant, the sort's one-pass bytes, and the measured-density spill
+   headroom;
 4. :func:`shortlist` ranks the certified set by modeled sort traffic —
    the measured dominant cost of the chunk budget — and hands the top-K
    to the probe-pass machinery (``tools/geomsearch.py`` reusing the
@@ -84,15 +83,6 @@ def stable2_sort_rows(chunk_bytes: int, block_rows: int, slots: int,
     return grid * slots * lanes
 
 
-def radix_slab_write_amplification(geom: Geometry) -> float:
-    """Slab bytes written per one-pass bytes for one partition level —
-    the round-6 pricing note's slack-factor write amplification, derived
-    from the CANDIDATE geometry instead of quoted: every block writes
-    ``3 * B * cap`` slab rows per ``3 * block_rows`` input rows."""
-    B = 1 << geom.radix_bits
-    return (B * geom.radix_cap) / geom.radix_block_rows
-
-
 def window_spill_risk(block_rows: int, slots: int) -> bool:
     """Does the measured worst-case density overflow this window's slot
     budget?  The PR-11 512-row dead-end branch, as arithmetic: 114 ends
@@ -115,8 +105,6 @@ class Candidate:
     sort_pass_bytes: int
     #: Peak single-kernel VMEM footprint over the candidate's plan set.
     vmem_peak_bytes: int
-    #: Radix slab write amplification (one partition level).
-    radix_amplification: float
     #: Measured-density spill risk of the candidate's compact window.
     spill_risk: bool
 
@@ -125,7 +113,6 @@ class Candidate:
                 "sort_rows": self.sort_rows,
                 "sort_pass_bytes": self.sort_pass_bytes,
                 "vmem_peak_bytes": self.vmem_peak_bytes,
-                "radix_amplification": round(self.radix_amplification, 3),
                 "spill_risk": self.spill_risk,
                 "geometry": self.geometry.as_dict()}
 
@@ -175,7 +162,7 @@ def price(geom: Geometry,
           chunk_bytes: int = PRICING_CHUNK_BYTES) -> dict:
     """The hbm-cost-model pricing of one candidate at ``chunk_bytes``:
     sort rows/pass bytes from the CANDIDATE's stable2 window, VMEM peak
-    over its plan set, radix amplification, spill headroom."""
+    over its plan set, spill headroom."""
     rows = stable2_sort_rows(chunk_bytes, geom.block_rows,
                              geom.compact_slots)
     plans = meta.geometry_plans(geom)
@@ -184,7 +171,6 @@ def price(geom: Geometry,
         "sort_rows": rows,
         "sort_pass_bytes": 2 * rows * 3 * 4,
         "vmem_peak_bytes": max(p.vmem_bytes for p in plans),
-        "radix_amplification": radix_slab_write_amplification(geom),
         "spill_risk": window_spill_risk(geom.block_rows,
                                         geom.compact_slots),
     }
@@ -196,7 +182,6 @@ def _candidate(geom: Geometry, axis: str, chunk_bytes: int) -> Candidate:
                      sort_rows=p["sort_rows"],
                      sort_pass_bytes=p["sort_pass_bytes"],
                      vmem_peak_bytes=p["vmem_peak_bytes"],
-                     radix_amplification=p["radix_amplification"],
                      spill_risk=p["spill_risk"])
 
 
@@ -213,7 +198,6 @@ LATTICE_AXES: dict = {
     "sort3": tuple((br, s) for br in (256, 384, 512)
                    for s in (72, 80, 88, 96, 104, 112, 120, 128)
                    if s <= br // 2),
-    "radix": tuple((b, sl) for b in (2, 3, 4, 5) for sl in (2, 4)),
 }
 
 
@@ -250,8 +234,6 @@ def enumerate_candidates(chunk_bytes: int = PRICING_CHUNK_BYTES
         add("pair_block_rows", pair_block_rows=pbr)
     for sbr, ss in LATTICE_AXES["sort3"]:
         add("sort3", sort3_block_rows=sbr, sort3_slots=ss)
-    for bits, slack in LATTICE_AXES["radix"]:
-        add("radix", radix_bits=bits, radix_slab_slack=slack)
     return out
 
 

@@ -250,7 +250,7 @@ class CostPass:
         # phantom pricing drift; its own strictly-below gate
         # (_combiner_gate_findings) owns that geometry instead.
         if config.resolved_backend() != "pallas" or \
-                config.sort_mode != "stable2" or config.sort_impl != "xla" \
+                config.sort_mode != "stable2" \
                 or config.resolved_combiner_slots:
             return []
         sort = costmodel.find_aggregation_sort(step, num_keys=2)

@@ -2,8 +2,8 @@
 
 TPU grids execute sequentially, and the shipped kernels lean on that hard:
 the tokenize carry scratch hands the lookback window from block to block,
-the radix partition accumulates SMEM histograms and a running spill
-scalar.  Those patterns are correct exactly when they keep a narrow
+and its running counters (overlong, tokens, spill) live in revisited SMEM
+outputs.  Those patterns are correct exactly when they keep a narrow
 discipline, and this lint checks the discipline statically on every
 traced kernel body:
 
@@ -149,7 +149,7 @@ class KernelRacePass:
                              "iteration 0 sees uninitialized memory"),
                     location=info.src,
                     hint="zero it under pl.when(first-iteration) like the "
-                         "tokenize carry / radix histogram idiom"))
+                         "tokenize carry idiom"))
         if not out and (carries_state or info.scratch):
             out.append(core.Finding(
                 severity=core.INFO, pass_id=self.pass_id, model=ctx.model,

@@ -10,12 +10,12 @@ Two legs, both from BlockSpec/grid/scratch shapes alone:
   ceiling, and the SMEM budget;
 * **shipped geometries** — the production kernel plans the modules declare
   via their metadata hooks (``ops/pallas/meta.production_plans()``) are
-  certified the same way, so the stable2/sort3/radix production shapes
+  certified the same way, so the stable2/sort3 production shapes
   stay covered even though analysis configs trace toy grids
   (:func:`certify_production_kernels`, run once per pipeline by the CLI).
 
 The pass also checks **spill-fallback reachability**: a kernel whose
-metadata declares spill semantics (compact tokenize, radix partition)
+metadata declares spill semantics (compact tokenize)
 emits a counter that callers MUST gate an exactness fallback on — a
 traced program containing such a kernel but no ``cond`` primitive at all
 has statically unreachable fallback, which is how "always exact" silently

@@ -23,8 +23,7 @@ import json
 import sys
 import time
 
-from mapreduce_tpu.config import (MERGE_STRATEGIES, Config,
-                                  PlatformRefusedError)
+from mapreduce_tpu.config import MERGE_STRATEGIES, Config
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -167,26 +166,13 @@ def build_parser() -> argparse.ArgumentParser:
                         "the aggregation sort's input ~1.45x at S=88; "
                         "windows denser than S fall back to the full path "
                         "for that chunk (always exact)")
-    p.add_argument("--sort-mode", choices=("sort3", "stable2", "segmin"),
+    p.add_argument("--sort-mode", choices=("sort3", "stable2"),
                    default="stable2",
                    help="aggregation sort strategy on the pallas fast path "
                         "(bit-identical results): 'stable2' drops the third "
                         "sort key via a lane-major kernel layout + stable "
-                        "2-key sort; 'segmin' trades it for a segmented min "
-                        "scan (CPU only — wedges the TPU). See "
-                        "tools/sortbench.py")
-    p.add_argument("--sort-impl", choices=("xla", "radix", "radix_partition"),
-                   default="xla",
-                   help="aggregation sort implementation on the packed fast "
-                        "path (bit-identical results): 'xla' = lax.sort, "
-                        "the measured floor; 'radix_partition' / 'radix' = "
-                        "the Pallas MSD digit partition with per-bucket "
-                        "finishing sorts (1 / 2 digit levels; priced "
-                        "LOSING from measured rates, shipped for on-chip "
-                        "falsification — round-6 chip notes). Like "
-                        "--sort-mode, applies to the packed fast path only "
-                        "(pallas wordcount family + gram builds); the xla "
-                        "wordcount path runs the generic build either way")
+                        "2-key sort; 'sort3' sorts on three keys over the "
+                        "slot-major layout")
     p.add_argument("--map-impl", choices=("split", "fused"), default="split",
                    help="pallas map-phase implementation (bit-identical "
                         "results): 'split' = compact kernel + XLA seam "
@@ -235,8 +221,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--rescue-overlong", type=int, default=None, metavar="R",
                    help="pallas backend: re-hash up to R >W-byte tokens per "
                         "chunk exactly via bounded XLA windows (URLs/markup "
-                        "on natural text; default auto: 1024 under sort3, "
-                        "off under segmin; 0 disables)")
+                        "on natural text; default 1024; 0 disables)")
     p.add_argument("--rescue-overlong-max", type=int, default=None,
                    metavar="R2",
                    help="second-tier rescue budget: chunks whose overlong "
@@ -607,7 +592,6 @@ def main(argv: list[str] | None = None) -> int:
                         pallas_max_token=args.max_token_bytes,
                         sketch_flush_every=args.sketch_flush_every,
                         sort_mode=args.sort_mode,
-                        sort_impl=args.sort_impl,
                         map_impl=args.map_impl,
                         merge_every=args.merge_every,
                         compact_slots=args.compact_slots,
@@ -696,24 +680,12 @@ def main(argv: list[str] | None = None) -> int:
     profiling.enable_compile_cache()
 
     # Honor a cpu request (--platform cpu / JAX_PLATFORMS=cpu) BEFORE any
-    # device use; later gates read the EFFECTIVE platform.
+    # device use.
     try:
-        effective = _apply_platform(args.platform)
+        _apply_platform(args.platform)
     except RuntimeError as e:  # cpu force could not land (backend already up)
         print(f"error: {e}", file=sys.stderr)
         return 2
-
-    if args.sort_mode == "segmin":
-        from mapreduce_tpu.config import SEGMIN_TPU_ERROR, segmin_allowed
-
-        # Fail with a clean message before any device work when a non-CPU
-        # platform is configured.  With NO platform configured (effective
-        # ''), jax may still resolve a local TPU — that case is caught by
-        # the deep trace-time guard in ops.table.from_packed_rows, whose
-        # ValueError the compute paths below surface as a clean exit 2.
-        if effective not in ("", "cpu") and not segmin_allowed():
-            print(f"error: {SEGMIN_TPU_ERROR}", file=sys.stderr)
-            return 2
 
     # One telemetry handle across every mode: the run ledger + flight
     # recorder (--ledger) and the registry snapshot (--metrics-out).  The
@@ -824,30 +796,23 @@ def _wordcount_main(args, paths, data, config, input_bytes: int,
         job = f"ngram{args.ngram}" if args.ngram > 1 else "wordcount"
         _batch_run_start(batch_tel, job, paths, config, input_bytes)
     t0 = time.perf_counter()
-    try:
-        with profiling.trace(args.profile):
-            if args.stream:
-                from mapreduce_tpu.runtime.executor import count_file
+    with profiling.trace(args.profile):
+        if args.stream:
+            from mapreduce_tpu.runtime.executor import count_file
 
-                result = count_file(paths, config=config, top_k=args.top_k or None,
-                                    distinct_sketch=args.distinct_sketch,
-                                    count_sketch=args.count_sketch or bool(args.estimate),
-                                    ngram=args.ngram,
-                                    merge_strategy=args.merge_strategy,
-                                    checkpoint_path=args.checkpoint,
-                                    checkpoint_every=args.checkpoint_every if args.checkpoint else 0,
-                                    retry=args.retry, telemetry=telemetry)
-            else:
-                from mapreduce_tpu.models import wordcount
+            result = count_file(paths, config=config, top_k=args.top_k or None,
+                                distinct_sketch=args.distinct_sketch,
+                                count_sketch=args.count_sketch or bool(args.estimate),
+                                ngram=args.ngram,
+                                merge_strategy=args.merge_strategy,
+                                checkpoint_path=args.checkpoint,
+                                checkpoint_every=args.checkpoint_every if args.checkpoint else 0,
+                                retry=args.retry, telemetry=telemetry)
+        else:
+            from mapreduce_tpu.models import wordcount
 
-                result = wordcount.count_ngrams(data, args.ngram, config) \
-                    if args.ngram > 1 else wordcount.count_words(data, config)
-    except PlatformRefusedError as e:
-        # Config-vs-platform refusals raised at trace time (the segmin TPU
-        # wedge guard) exit cleanly; any OTHER ValueError is a real bug and
-        # keeps its traceback.
-        print(f"error: {e}", file=sys.stderr)
-        return 2
+            result = wordcount.count_ngrams(data, args.ngram, config) \
+                if args.ngram > 1 else wordcount.count_words(data, config)
     elapsed = time.perf_counter() - t0
     if batch_tel is not None:
         # Result-derived data record: the batch path runs one jitted

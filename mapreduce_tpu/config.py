@@ -12,25 +12,9 @@ import dataclasses
 from typing import Optional
 
 
-class PlatformRefusedError(ValueError):
-    """A config knob refused against the resolved runtime platform (raised
-    at trace time, after construction-time validation can no longer see the
-    platform).  The CLI maps exactly this to a clean exit instead of
-    blanket-catching ValueError around all compute."""
-
-
-SEGMIN_TPU_ERROR = (
-    "sort_mode='segmin' is disabled on the TPU backend: its stream-sized "
-    "associative_scan wedges the chip for >30 min (measured 3x in round 4, "
-    "round 4) — on a shared device that takes down every tenant.  Use "
-    "sort_mode='sort3' (bit-identical results), run the A/B on CPU, or set "
-    "MAPREDUCE_ALLOW_SEGMIN=1 to re-measure deliberately.")
-
-
 #: Salt width for combiner='salt': one hot key spreads over 2**3 = 8 sort
-#: segments — enough to defeat the measured ~4x radix hot-key slab
-#: amplification while keeping the de-salt coalesce's collision envelope
-#: a single-digit multiple of the documented 64-bit key envelope.
+#: segments while the de-salt coalesce's collision envelope stays a
+#: single-digit multiple of the documented 64-bit key envelope.
 COMBINER_SALT_BITS = 3
 
 #: The collective merge strategies the runtime builds, mirrored here so
@@ -38,15 +22,6 @@ COMBINER_SALT_BITS = 3
 #: source of truth; the bijection is test-pinned in test_collective.py).
 MERGE_STRATEGIES = ("tree", "gather", "keyrange", "hier-kr-tree",
                     "hier-tree-tree")
-
-
-def radix_slab_cap(bits: int, block_rows: int, slab_slack: int) -> int:
-    """Resolved radix slab rows per (block, lane, bucket): the slack
-    multiple of the uniform share, clamped to the block — the ONE owner
-    of the clamp (Geometry validation, the meta plan constructor, and
-    the kernel wrapper all call this, so the certifier can never
-    desynchronize from what the kernel binds)."""
-    return min(slab_slack * block_rows // (1 << bits), block_rows)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,13 +68,6 @@ class Geometry:
     #: the head row is pinned at 64 = the W <= 63 bound, so 96 is the
     #: smallest tile-aligned plane that holds it).
     aux_rows: int = 96
-    #: Radix partition digit width (B = 2**bits buckets per level).
-    radix_bits: int = 3
-    radix_block_rows: int = 256
-    #: Slab budget per (block, lane, bucket) as a multiple of the uniform
-    #: share block_rows/B — the write-amplification factor of the round-6
-    #: pricing note, now a searchable knob.
-    radix_slab_slack: int = 4
 
     def __post_init__(self) -> None:
         for name in ("block_rows", "combiner_block_rows", "pair_block_rows"):
@@ -145,31 +113,6 @@ class Geometry:
                 f"aux_rows must be a multiple of 32 in [96, 512] (the "
                 "pinned head row at 64 needs the plane past it), got "
                 f"{self.aux_rows}")
-        if not 1 <= self.radix_bits <= 5:
-            raise ValueError(
-                f"radix_bits must be in [1, 5] (B output-ref triples are "
-                f"unrolled in the kernel), got {self.radix_bits}")
-        if self.radix_block_rows % 8 \
-                or not 64 <= self.radix_block_rows <= (1 << 20):
-            raise ValueError(
-                f"radix_block_rows must be a multiple of 8 in [64, 2**20], "
-                f"got {self.radix_block_rows}")
-        if self.radix_slab_slack < 1:
-            raise ValueError(
-                f"radix_slab_slack must be >= 1, got {self.radix_slab_slack}")
-        cap = radix_slab_cap(self.radix_bits, self.radix_block_rows,
-                             self.radix_slab_slack)
-        if cap < 8 or cap % 8:
-            raise ValueError(
-                f"radix slab cap {cap} (= slack*block_rows/B, clamped to "
-                "block_rows) must be a multiple of 8 and >= 8; adjust "
-                "radix_block_rows/radix_bits/radix_slab_slack")
-
-    @property
-    def radix_cap(self) -> int:
-        """Resolved per-(block, lane, bucket) slab rows (:func:`radix_slab_cap`)."""
-        return radix_slab_cap(self.radix_bits, self.radix_block_rows,
-                              self.radix_slab_slack)
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -189,16 +132,6 @@ GEOMETRY_PRESETS = {
     "tall512": Geometry(block_rows=512),
     "combiner16": Geometry(combiner_slots=16),
 }
-
-
-def segmin_allowed() -> bool:
-    """Single owner of the MAPREDUCE_ALLOW_SEGMIN override parse: the raw
-    string truthiness trap ('0' would bypass the wedge guard) is avoided by
-    treating only explicit affirmative values as opt-in."""
-    import os
-
-    return os.environ.get("MAPREDUCE_ALLOW_SEGMIN", "").lower() \
-        in ("1", "true", "yes")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -266,59 +199,28 @@ class Config:
     # per step — a TIGHTER bound).  Costs K * batch_uniques * 6 words of
     # device state.  1 = merge every step.
     merge_every: int = 1
-    # Aggregation sort strategy for the packed fast path (the single-chip
-    # floor: the 3-array sort over the pair-compacted stream is 25-85 ms of
-    # the ~102 ms chunk budget, round-4/5 chip notes).  'sort3' (default) carries
-    # `packed` as a third sort key so each key segment's head row is its
-    # first occurrence.  'stable2' drops the third comparator key: the
-    # kernel writes its compacted planes LANE-MAJOR (flattened stream in
-    # global byte-position order) and a STABLE two-key sort recovers first
-    # occurrence from tie order — the round-4 sortbench measured the
-    # comparator-width cut at ~40% of the sort's compute (173.8 -> 144.9 ms
-    # incl. dispatch, 16.8M rows).  Requires the compact kernel path
-    # (compact_slots > 0); window geometry moves to block_rows=384 /
-    # 128 slots (measured spill-free: max 114 ends per 384-byte window on
-    # Zipf, 75 natural — tools/density.py), whose transposed (128, 128)
-    # output blocks are fully tile-aligned stores.  'segmin' sorts with
-    # only the two key lanes in the
-    # comparator (packed rides as payload) and recovers first occurrence
-    # with a segmented running-min instead.  Bit-identical results;
-    # tools/sortbench.py measures both.  'segmin' is REFUSED on the TPU
-    # backend at trace time: its stream-sized associative_scan wedges the
-    # chip for >30 min (3 independent observations, round-4 chip notes)
-    # — a one-flag footgun on a shared device.  The CPU A/B stays alive
-    # (tests, sortbench's gated scan path); MAPREDUCE_ALLOW_SEGMIN=1
-    # overrides for deliberate re-measurement.  Default 'stable2': measured
-    # on-chip 2026-07-31 (round 5) against the same-day sort3 records —
-    # zipf 0.4263 vs 0.4024 GB/s, natural 0.3653 vs 0.3448, webby (rescue
-    # firing) 0.2748 vs 0.2659 — with the bit-identity suite
-    # (tests/test_stable2.py) and an on-chip kernel parity smoke
-    # (tools/kernel_smoke.py) holding both modes equal.
+    # Aggregation sort strategy for the packed fast path (the pallas
+    # wordcount family and the packed gram build on both backends; the xla
+    # wordcount path runs the generic 7-array build, where it does not
+    # apply).  Both modes sort with jax.lax.sort and give identical
+    # results; they differ in the comparator and in the kernel layout they
+    # need.  'stable2' (default): the compact kernel writes its planes
+    # LANE-MAJOR, so the flattened stream is in global byte-position order,
+    # and a STABLE sort on the two key lanes alone recovers each key's
+    # first occurrence from tie order (the comparator-width cut measured
+    # ~40% of the sort's compute, round-4 chip notes).  Windows are
+    # block_rows=384 with 128 slots (measured spill-free: max 114 ends per
+    # 384-byte window on Zipf, tools/density.py), whose transposed (128,
+    # 128) output blocks are tile-aligned stores.  It is the default
+    # because it won the round-5 on-chip A/B against sort3 on every bench
+    # corpus (Zipf 0.4263 vs 0.4024 GB/s).  'sort3': `packed` (the
+    # token's position and length) is a third sort key, so a segment's
+    # head row is its first occurrence whatever the stream's order; the
+    # compact kernel then writes slot-major 256-row windows with 88 slots.
+    # Pair-layout and concatenated (column + seam) streams are not
+    # position-ordered, so the map's full-resolution path and the spill
+    # fallback sort with sort3 in either mode.
     sort_mode: str = "stable2"
-    # Aggregation sort IMPLEMENTATION for the packed fast path — orthogonal
-    # to sort_mode, which picks the comparator STRATEGY.  'xla' (default):
-    # jax.lax.sort, measured at 2.6-3.4 effective HBM passes on the 11.2M-
-    # row stream (round-6 chip notes pricing note).  'radix_partition':
-    # one Pallas MSD digit partition (per-block VMEM bucket compaction into
-    # static slabs + SMEM histograms, ops/pallas/radix.py) finished by
-    # per-bucket blocked XLA sorts.  'radix': two digit levels before the
-    # (smaller) finishing sorts.  Both radix modes are bit-identical to
-    # 'xla' — stable tie order included; adversarial bucket skew falls back
-    # to the XLA sort under a lax.cond (the compact-path spill idiom) — and
-    # serve sort3 and stable2 alike (ties resolve by `packed`, which is
-    # sort3's definition and stable2's tie order under its position-ordered
-    # input precondition).  The round-6 pricing from measured rates has
-    # them LOSING 2-3x (static slabs pay a slack-factor write amplification
-    # that only hardware scatter could avoid, and TPU has none), so 'xla'
-    # stays default until a chip A/B falsifies the arithmetic.  segmin is xla-only (its scan
-    # recovery needs packed as an unordered payload).  Scope — the same as
-    # sort_mode's: the PACKED fast path only, i.e. the pallas wordcount
-    # family and the packed gram build on both backends; the xla
-    # wordcount path runs the generic 7-array build, where neither knob
-    # applies (an xla-backend wordcount A/B of sort impls measures the
-    # same generic sort twice — run radix A/Bs on the pallas path, as
-    # bench.py does).
-    sort_impl: str = "xla"
     # Map-phase IMPLEMENTATION for the pallas backend (ISSUE 6) — the seam
     # between "tokenize with an XLA fix-up chain" and "one fused kernel".
     # 'split' (default): the round-4/5 shipped path — the compact kernel
@@ -336,7 +238,7 @@ class Config:
     # kernel in pair mode).  The costcheck hbm-cost pass prices the gap
     # and ERROR-gates `wordcount_fused` strictly below the split baseline;
     # 'split' stays default until an on-chip window confirms the predicted
-    # win (round-9 chip notes — the radix round-6 discipline).  Applies
+    # win (round-9 chip notes).  Applies
     # to the pallas map paths (wordcount family + n-grams); the xla
     # backend has no kernel to fuse and ignores it.
     map_impl: str = "split"
@@ -362,10 +264,7 @@ class Config:
     # Guarded by lax.cond(overlong > 0): overlong-free corpora (both bench
     # generators) never pay.  Residuals (counts past the budget, tokens
     # longer than rescue_window - 1) stay in dropped_* accounting.  0 = off
-    # (the round-3 behavior).  Requires sort_mode='sort3' (poison rows are
-    # extracted off the aggregation sort's third key): None (default)
-    # resolves to 1024 under sort3 and 0 under segmin, while an EXPLICIT
-    # positive value with segmin is an error, not a silently dropped knob.
+    # (the round-3 behavior).  None (default) resolves to 1024.
     rescue_overlong: Optional[int] = None
     # Rescue lookback bound in bytes: tokens up to rescue_window - 1 bytes
     # are rescued exactly.  192 covers p99.9 of webby-proxy token lengths
@@ -426,9 +325,8 @@ class Config:
     # is a documented no-op, like compact_slots on the xla backend.
     # 'salt': key-salting for pathological single-key streams — the
     # packed table build XORs low position bits into key_lo so one
-    # scorching key spreads over 2**COMBINER_SALT_BITS sort segments
-    # (radix slab amplification on hot keys measured ~4x, round-6 chip
-    # notes), then de-salts and re-reduces the capacity-sized table
+    # scorching key spreads over 2**COMBINER_SALT_BITS sort segments,
+    # then de-salts and re-reduces the capacity-sized table
     # exactly at the reduce seam.  Envelope (ops/table.from_packed_rows
     # documents both legs): exact de-salting widens the documented
     # ~n^2/2^65 64-bit key-collision envelope by the salt factor (8x at
@@ -438,7 +336,7 @@ class Config:
     # capacity (under unique overflow the cutoff falls on salted key
     # order; occurrence totals stay conserved via dropped accounting).
     # Applies to the packed fast path (pallas wordcount family + gram
-    # builds on both backends), the sort_mode/sort_impl scope.
+    # builds on both backends), the sort_mode scope.
     # 'auto': resolve from the PREVIOUS run's data-health verdict — the
     # first config knob chosen by the data, not the operator: skew-hot ->
     # 'hot-cache', anything else (or no ledger history) -> 'off'.  The
@@ -470,7 +368,7 @@ class Config:
     # geometries (the emission set, fallback exactness and accounting
     # are geometry-independent — tested); only the cost moves, which is
     # the point.  Scope: the pallas kernel paths (wordcount family +
-    # grams + the radix sort seam); the xla backend has no kernel
+    # grams); the xla backend has no kernel
     # geometry and ignores it.
     geometry: object = None
     # Second-tier rescue budget (round-4 review weak #4): URL-heavy text carries
@@ -506,8 +404,8 @@ class Config:
     # deterministic-jitter schedule, the completion-token wall-clock
     # timeout (a hung device reads as a typed fault instead of a silent
     # stall), and whether resource-classed exhaustion steps down the
-    # degradation ladder (revert-geometry -> combiner-off -> map-split ->
-    # sort-xla) before giving up.
+    # degradation ladder (revert-geometry -> combiner-off -> map-split)
+    # before giving up.
     failure_policy: object = None
     # Collective merge strategy for the global reduction (ISSUE 20): a
     # name from ``MERGE_STRATEGIES`` ('tree', 'gather', 'keyrange',
@@ -544,18 +442,10 @@ class Config:
                 f"sketch_flush_every must be >= 1, got {self.sketch_flush_every}")
         if self.backend not in ("auto", "xla", "pallas"):
             raise ValueError(f"unknown backend {self.backend!r}")
-        if self.sort_mode not in ("sort3", "stable2", "segmin"):
+        if self.sort_mode not in ("sort3", "stable2"):
             raise ValueError(f"unknown sort_mode {self.sort_mode!r}")
-        if self.sort_impl not in ("xla", "radix", "radix_partition"):
-            raise ValueError(f"unknown sort_impl {self.sort_impl!r}")
         if self.map_impl not in ("split", "fused"):
             raise ValueError(f"unknown map_impl {self.map_impl!r}")
-        if self.sort_impl != "xla" and self.sort_mode == "segmin":
-            raise ValueError(
-                "sort_impl='radix'/'radix_partition' requires sort_mode "
-                "'sort3' or 'stable2': segmin recovers first occurrence "
-                "with a segmented scan over packed-as-payload, an order "
-                "the radix path's tie-by-packed contract replaces")
         if self.sort_mode == "stable2" and self.compact_slots is not None \
                 and self.compact_slots != 128:
             # Mosaic requires the last block dim divisible by 128, and the
@@ -584,12 +474,6 @@ class Config:
                 and self.rescue_overlong_max < 0:
             raise ValueError(f"rescue_overlong_max must be >= 0, "
                              f"got {self.rescue_overlong_max}")
-        if self.rescue_overlong:
-            if self.sort_mode == "segmin":
-                raise ValueError(
-                    "rescue_overlong requires sort_mode='sort3' or "
-                    "'stable2' (poison extraction needs the poison segment "
-                    "position-ordered); set rescue_overlong=0 to use segmin")
         if self.rescue_slots:
             if self.backend != "xla" \
                     and self.rescue_window <= self.pallas_max_token + 1:
@@ -603,14 +487,6 @@ class Config:
         if self.combiner not in ("off", "hot-cache", "salt", "auto"):
             raise ValueError(f"unknown combiner {self.combiner!r} (expected "
                              "'off', 'hot-cache', 'salt' or 'auto')")
-        if self.combiner == "salt" and self.sort_mode == "segmin":
-            # Fail at construction, not minutes into a trace: segmin keeps
-            # packed as an unordered payload, so the de-salt has no
-            # per-segment position order to recover the XOR from.
-            raise ValueError(
-                "combiner='salt' requires sort_mode='sort3' or 'stable2' "
-                "(the de-salt reads each kept row's own position; segmin "
-                "keeps packed as an unordered payload)")
         if self.combiner_slots is not None:
             # Mirrors the kernel wrapper's envelope (fail at construction,
             # not mid-trace): one or more whole (8, 128) cache tiles.
@@ -707,9 +583,7 @@ class Config:
     @property
     def rescue_slots(self) -> int:
         """The resolved overlong-rescue budget (see ``rescue_overlong``)."""
-        if self.rescue_overlong is None:
-            return 0 if self.sort_mode == "segmin" else 1024
-        return self.rescue_overlong
+        return 1024 if self.rescue_overlong is None else self.rescue_overlong
 
     @property
     def rescue_slots_max(self) -> int:
@@ -835,19 +709,6 @@ class Config:
         g = self.resolved_geometry
         return g.aux_rows \
             if g.aux_rows != DEFAULT_GEOMETRY.aux_rows else None
-
-    @property
-    def resolved_radix_geometry(self) -> tuple | None:
-        """(bits, block_rows, slab_slack) for the radix sort seam, or
-        None for the module defaults — the None-sentinel keeps the
-        radix wrapper's call-time default resolution (tests shrink the
-        module geometry globally) intact on default configs."""
-        g = self.resolved_geometry
-        d = DEFAULT_GEOMETRY
-        if (g.radix_bits, g.radix_block_rows, g.radix_slab_slack) == \
-                (d.radix_bits, d.radix_block_rows, d.radix_slab_slack):
-            return None
-        return (g.radix_bits, g.radix_block_rows, g.radix_slab_slack)
 
     @property
     def resolved_prefetch_depth(self) -> int:

@@ -22,16 +22,6 @@ from mapreduce_tpu.config import Config
 ANALYSIS_CONFIG = Config(chunk_bytes=1 << 10, table_capacity=512,
                          backend="xla")
 
-# Radix-sort-impl wordcount (round 6): the production-shaped pallas program
-# with the Pallas radix partition in the aggregation seam, at the smallest
-# chunk the pallas backend admits (whole lane segments of 2W+2 bytes) —
-# registered so the graphcheck gate (hostsync / sharding / overflow /
-# algebra passes) certifies the radix program before dispatch like every
-# other shipped family.
-RADIX_ANALYSIS_CONFIG = Config(chunk_bytes=128 * 66, table_capacity=512,
-                               backend="pallas",
-                               sort_impl="radix_partition")
-
 # Production-shaped pallas wordcount (the shipped default path: stable2
 # lane-major compact kernel + XLA aggregation sort), at one full 384-row
 # kernel window per lane.  Registered for the costcheck passes: the cost
@@ -101,21 +91,12 @@ def _sketch(config: Config):
     return SketchedWordCountJob(WordCountJob(config))
 
 
-def _wordcount_radix(config: Config):
-    from mapreduce_tpu.models.wordcount import WordCountJob
-
-    # Pinned config like grep's pinned pattern: the model EXISTS to put the
-    # radix program in front of the analysis passes, so the caller's sizing
-    # config is deliberately ignored.
-    del config
-    return WordCountJob(RADIX_ANALYSIS_CONFIG)
-
-
 def _wordcount_pallas(config: Config):
     from mapreduce_tpu.models.wordcount import WordCountJob
 
-    # Pinned config (see _wordcount_radix): the model exists to put the
-    # shipped stable2 pallas program in front of the costcheck passes.
+    # Pinned config like grep's pinned pattern: the model EXISTS to put the
+    # shipped stable2 pallas program in front of the costcheck passes, so
+    # the caller's sizing config is deliberately ignored.
     del config
     return WordCountJob(PALLAS_ANALYSIS_CONFIG)
 
@@ -123,7 +104,7 @@ def _wordcount_pallas(config: Config):
 def _wordcount_fused(config: Config):
     from mapreduce_tpu.models.wordcount import WordCountJob
 
-    # Pinned config (see _wordcount_radix): the model exists to put the
+    # Pinned config (see _wordcount_pallas): the model exists to put the
     # fused map program in front of the full graphcheck/costcheck gate,
     # with its cost baseline error-gated below the split path's.
     del config
@@ -133,7 +114,7 @@ def _wordcount_fused(config: Config):
 def _wordcount_combiner(config: Config):
     from mapreduce_tpu.models.wordcount import WordCountJob
 
-    # Pinned config (see _wordcount_radix): the Zipf-shaped combiner-ON
+    # Pinned config (see _wordcount_pallas): the Zipf-shaped combiner-ON
     # program — the hbm-cost combiner gate prices it strictly below its
     # combiner-off twin, and the vmem/kernelrace passes certify the
     # hot-key cache's revisited-output discipline.
@@ -194,7 +175,7 @@ def _fleet(job, processes: int, local_devices: int, merge: str = "tree"):
 def _wordcount_fleet2(config: Config):
     from mapreduce_tpu.models.wordcount import WordCountJob
 
-    # Pinned config (see _wordcount_radix): the 2-host x 4-device fleet
+    # Pinned config (see _wordcount_pallas): the 2-host x 4-device fleet
     # twin — the hierarchical tree merge's butterfly runs per level
     # (inner ICI axis first, one merged payload across DCN), so the
     # collective-cost pass prices a real 2-D ICI/DCN program in CI.
@@ -234,7 +215,6 @@ _REGISTRY: Dict[str, Callable[[Config], object]] = {
     "sample": _sample,
     "ngram": _ngram,
     "sketch": _sketch,
-    "wordcount_radix": _wordcount_radix,
     "wordcount_pallas": _wordcount_pallas,
     "wordcount_fused": _wordcount_fused,
     "wordcount_combiner": _wordcount_combiner,
@@ -263,5 +243,4 @@ def build_model(name: str, config: Config = ANALYSIS_CONFIG):
 
 __all__ = ["ANALYSIS_CONFIG", "COMBINER_ANALYSIS_CONFIG",
            "FUSED_ANALYSIS_CONFIG", "NOCOMBINER_ANALYSIS_CONFIG",
-           "PALLAS_ANALYSIS_CONFIG", "RADIX_ANALYSIS_CONFIG",
-           "build_model", "model_names"]
+           "PALLAS_ANALYSIS_CONFIG", "build_model", "model_names"]

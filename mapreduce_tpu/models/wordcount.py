@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from mapreduce_tpu import constants
-from mapreduce_tpu.config import Config, DEFAULT_CONFIG, segmin_allowed
+from mapreduce_tpu.config import Config, DEFAULT_CONFIG
 from mapreduce_tpu.data import reader as reader_mod
 from mapreduce_tpu.ops import datastats
 from mapreduce_tpu.ops import sketch as sketch_ops
@@ -257,12 +257,6 @@ def _map_stream(chunk: jax.Array, config: Config, capacity: int,
                                 lambda _: ret(accounted(t, overlong),
                                               zero_u32), None)
 
-        # The spill-fallback / non-compact aggregation must not use stable2:
-        # pair-layout streams are NOT position-ordered (rows interleave
-        # lanes), so first-occurrence recovery needs the third sort key.
-        concat_sort_mode = "sort3" if config.sort_mode == "stable2" \
-            else config.sort_mode
-
         def aggregate_stream(stream, overlong, mode, cache=None):
             """ONE packed build over a single complete stream — the shared
             tail of the split concat path and the fused map path (whose
@@ -284,9 +278,7 @@ def _map_stream(chunk: jax.Array, config: Config, capacity: int,
                 max_token_bytes=config.pallas_max_token,
                 max_pos=int(chunk.shape[0]), sort_mode=mode,
                 rescue_slots=config.rescue_slots_max,
-                sort_impl=config.sort_impl,
-                salt_bits=config.resolved_salt_bits,
-                radix_geometry=config.resolved_radix_geometry)
+                salt_bits=config.resolved_salt_bits)
             if not config.rescue_slots:
                 res = ret(accounted(built, overlong), zero_u32)
             else:
@@ -303,9 +295,12 @@ def _map_stream(chunk: jax.Array, config: Config, capacity: int,
             # One aggregation over column + seam emissions together: the
             # seam rows are ~8.5K entries, absorbed by the big sort for
             # free, where a separate seam table + merge cost a second
-            # (fixed-overhead-bound) reduce pass per chunk.
+            # (fixed-overhead-bound) reduce pass per chunk.  Pair-layout
+            # and concatenated streams are NOT position-ordered (rows
+            # interleave lanes), so first occurrence needs sort3's third
+            # sort key whatever the configured sort_mode.
             return aggregate_stream(pallas_tok.concat_streams(col, seam),
-                                    overlong, concat_sort_mode)
+                                    overlong, "sort3")
 
         def aggregate_stable2(col, seam, overlong):
             """Split aggregation for the lane-major layout: the column
@@ -326,9 +321,7 @@ def _map_stream(chunk: jax.Array, config: Config, capacity: int,
                 max_token_bytes=config.pallas_max_token,
                 max_pos=int(chunk.shape[0]), sort_mode="stable2",
                 rescue_slots=config.rescue_slots_max,
-                sort_impl=config.sort_impl,
-                salt_bits=config.resolved_salt_bits,
-                radix_geometry=config.resolved_radix_geometry)
+                salt_bits=config.resolved_salt_bits)
             seam_tbl = table_ops.from_stream(
                 seam,
                 min(capacity,
@@ -406,7 +399,7 @@ def _map_stream(chunk: jax.Array, config: Config, capacity: int,
                     block_rows=config.resolved_pair_block_rows,
                     aux_rows=config.resolved_aux_rows)
                 return seamed_ret(aggregate_stream(stream, overlong,
-                                                   concat_sort_mode)), \
+                                                   "sort3")), \
                     overlong
 
             def fused_full(_):
@@ -441,11 +434,10 @@ def _map_stream(chunk: jax.Array, config: Config, capacity: int,
             # Lane-major fused streams stay in global byte-position order
             # (cross-seam tokens land in their start-position slot), so the
             # stable2 tie-order contract holds over the single stream.
-            mode = "stable2" if lane_major else concat_sort_mode
             return assemble(jax.lax.cond(
                 spill == 0,
-                lambda _: seamed_ret(aggregate_stream(stream, overlong,
-                                                      mode, cache=cache)),
+                lambda _: seamed_ret(aggregate_stream(
+                    stream, overlong, config.sort_mode, cache=cache)),
                 fused_full, None), overlong, spill,
                 cache=cache, spill_gate=spill)
 
@@ -563,9 +555,7 @@ def _ngram_step(data: jax.Array, capacity: int, n: int,
     gs = ngram_ops.mark_long_spans(tok_ops.ngrams(tok_ops.tokenize(data), n))
     return ngram_ops.gram_table(gs, capacity, 0, max_pos=data.shape[0],
                                 sort_mode=config.sort_mode,
-                                sort_impl=config.sort_impl,
-                                salt_bits=config.resolved_salt_bits,
-                                radix_geometry=config.resolved_radix_geometry)
+                                salt_bits=config.resolved_salt_bits)
 
 
 def count_ngrams(data: bytes, n: int, config: Config = DEFAULT_CONFIG) -> WordCountResult:
@@ -765,12 +755,9 @@ class WordCountJob:
     def program_key(self):
         """Everything the hooks read when traced: the job's type, the whole
         Config and the sizes taken from it (``identity()`` leaves out the
-        knobs that change the program but not the result).  The segmin
-        refusal reads the environment at trace time, so its override is
-        part of the key."""
+        knobs that change the program but not the result)."""
         return (type(self), self.config, self.capacity, self.batch_capacity,
-                self.merge_every,
-                self.config.sort_mode == "segmin" and segmin_allowed())
+                self.merge_every)
 
 
 class TopKTable(NamedTuple):
@@ -892,9 +879,7 @@ class NGramCountJob(WordCountJob):
         return ngram_ops.gram_table(
             gs, self.batch_capacity, chunk_id, max_pos=chunk.shape[0],
             sort_mode=self.config.sort_mode,
-            sort_impl=self.config.sort_impl,
-            salt_bits=self.config.resolved_salt_bits,
-            radix_geometry=self.config.resolved_radix_geometry)
+            salt_bits=self.config.resolved_salt_bits)
 
     # -- exact cross-chunk grams (streamed runs) ----------------------------
 
@@ -924,9 +909,7 @@ class NGramCountJob(WordCountJob):
             t = ngram_ops.gram_table(
                 gs, self.batch_capacity, chunk_id, max_pos=chunk.shape[0],
                 sort_mode=self.config.sort_mode,
-                sort_impl=self.config.sort_impl,
-                salt_bits=self.config.resolved_salt_bits,
-                radix_geometry=self.config.resolved_radix_geometry)
+                salt_bits=self.config.resolved_salt_bits)
             summ = ngram_ops.summary_from_stream(stream, chunk_id, self.n)
         gathered = jax.lax.all_gather(summ, axis_name=axis)  # leaves [D, n-1]
         return NGramUpdate(batch=t, summaries=gathered,

@@ -141,9 +141,7 @@ def mark_long_spans(stream: TokenStream) -> TokenStream:
 
 def gram_table(gs: TokenStream, capacity: int, pos_hi: jax.Array | int,
                max_pos: int, sort_mode: str = "stable2",
-               sort_impl: str = "xla",
-               salt_bits: int = 0,
-               radix_geometry: tuple | None = None) -> table_ops.CountTable:
+               salt_bits: int = 0) -> table_ops.CountTable:
     """Aggregate a position-ordered gram stream into a count table.
 
     Both backends' gram streams arrive in ascending start-position order
@@ -164,17 +162,16 @@ def gram_table(gs: TokenStream, capacity: int, pos_hi: jax.Array | int,
     span the whole chunk).
 
     ``salt_bits`` (``Config.combiner='salt'``, ISSUE 11): a
-    single-hot-gram stream is exactly as pathological for the radix slab
-    path as a single hot word, so the salt tier rides the shared packed
-    build — spread over salted segments, de-salted exactly at the reduce
+    single-hot-gram stream is exactly as skewed as a single hot word, so
+    the salt tier rides the shared packed build — spread over salted
+    segments, de-salted exactly at the reduce
     (:func:`...ops.table.from_packed_rows`).  The gram family's hot-key
     CACHE tier does not exist: deleting duplicate tokens would break the
     position adjacency grams are formed from, so 'hot-cache' is a
     documented no-op here.
     """
     # pos << 7 needs pos < 2**25; the padded chunk length is a trace-time
-    # constant, so the gate is static.  (The generic fallback ignores
-    # sort_impl: the radix seam covers the packed build only.)
+    # constant, so the gate is static.
     if max_pos > (1 << 25):
         return table_ops.from_stream(gs, capacity, pos_hi=pos_hi)
     # Sentinel-collision proof (ADVICE r5): a live row packs to
@@ -191,13 +188,10 @@ def gram_table(gs: TokenStream, capacity: int, pos_hi: jax.Array | int,
     len7 = jnp.minimum(gs.length, jnp.uint32(127))
     packed = jnp.where(live, (gs.pos << jnp.uint32(7)) | len7, _SENT_PACKED)
     # sort_mode passes through unchanged: stable2's position-order
-    # precondition holds here (docstring), sort3/segmin have none, and
-    # from_packed_rows owns the segmin-on-TPU refusal.  sort_impl rides
-    # along so the gram family inherits the radix A/B with no extra knob.
+    # precondition holds here (docstring), and sort3 has none.
     t = table_ops.from_packed_rows(
         gs.key_hi, gs.key_lo, packed, jnp.sum(gs.count), capacity, pos_hi,
-        len_bits=7, sort_mode=sort_mode, sort_impl=sort_impl,
-        salt_bits=salt_bits, radix_geometry=radix_geometry)
+        len_bits=7, sort_mode=sort_mode, salt_bits=salt_bits)
     occ = t.occupied()
     return t._replace(length=jnp.where(
         occ & (t.length == jnp.uint32(127)),
@@ -247,9 +241,8 @@ def ngram_map_with_summary(chunk: jax.Array, n: int, capacity: int,
     key_hi, key_lo, packed = position_sorted(stream)
     gs = mark_long_spans(grams_from_sorted(key_hi, key_lo, packed, n))
     t = gram_table(gs, capacity, pos_hi, max_pos=chunk.shape[0],
-                   sort_mode=config.sort_mode, sort_impl=config.sort_impl,
-                   salt_bits=config.resolved_salt_bits,
-                   radix_geometry=config.resolved_radix_geometry)
+                   sort_mode=config.sort_mode,
+                   salt_bits=config.resolved_salt_bits)
     # Live sorted rows = real tokens + one poison row per overlong end.
     all_tokens = stream.total + overlong
     nm1 = jnp.uint32(n - 1)

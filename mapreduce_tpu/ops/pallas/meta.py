@@ -156,35 +156,12 @@ def tokenize_plan(block_rows: int = 256, compact_slots: int = 0,
         vmem_limit_bytes=64 * 1024 * 1024 if compact_slots else None)
 
 
-def radix_plan(bits: int = 3, block_rows: int = 256,
-               slab_slack: int = 4) -> VmemPlan:
-    """Static VMEM/SMEM footprint of one radix-partition geometry — the
-    arithmetic behind ``ops/pallas/radix.vmem_plan`` (which delegates
-    here)."""
-    from mapreduce_tpu.config import radix_slab_cap
-
-    B = 1 << bits
-    cap = radix_slab_cap(bits, block_rows, slab_slack)
-    bufs = [Buffer(f"plane-in[{i}]", "vmem", block_rows * _LANES * 4, True)
-            for i in range(3)]
-    bufs += [Buffer(f"slab-out[{b}]", "vmem", cap * _LANES * 4, True)
-             for b in range(3 * B)]
-    bufs.append(Buffer("histogram", "smem", B * 4, False))
-    bufs.append(Buffer("spill", "smem", 4, False))
-    return VmemPlan(
-        kernel="_partition_kernel",
-        geometry=f"bits={bits} block_rows={block_rows} "
-                 f"slab_slack={slab_slack} (cap={cap})",
-        buffers=tuple(bufs))
-
-
 def geometry_plans(geom) -> list[VmemPlan]:
     """Every kernel footprint one :class:`~mapreduce_tpu.config.Geometry`
     implies — the stable2 compact window, the sort3 compact and pair
-    variants, the fused map path, the hot-key combiner window, the fused
-    spill fallback, and both radix digit widths (the candidate's own and
-    the widest legal B, the register-pressure extreme).  The geometry
-    search certifies candidates through exactly this list."""
+    variants, the fused map path, the hot-key combiner window and the
+    fused spill fallback.  The geometry search certifies candidates
+    through exactly this list."""
     return [
         tokenize_plan(block_rows=geom.block_rows,
                       compact_slots=geom.compact_slots, lane_major=True),
@@ -200,10 +177,6 @@ def geometry_plans(geom) -> list[VmemPlan]:
                       combiner_slots=geom.combiner_slots),
         tokenize_plan(block_rows=geom.pair_block_rows, fused=True,
                       aux_rows=geom.aux_rows),
-        radix_plan(bits=geom.radix_bits, block_rows=geom.radix_block_rows,
-                   slab_slack=geom.radix_slab_slack),
-        radix_plan(bits=5, block_rows=geom.radix_block_rows,
-                   slab_slack=geom.radix_slab_slack),
     ]
 
 

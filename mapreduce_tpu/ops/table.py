@@ -318,9 +318,7 @@ def _build(key_hi, key_lo, pos_hi, pos_lo, count, count_hi, length,
 def from_packed_rows(key_hi: jax.Array, key_lo: jax.Array, packed: jax.Array,
                      total: jax.Array, capacity: int, pos_hi: jax.Array | int,
                      len_bits: int = 6, sort_mode: str = "sort3",
-                     rescue_slots: int = 0, sort_impl: str = "xla",
-                     salt_bits: int = 0,
-                     radix_geometry: tuple | None = None):
+                     rescue_slots: int = 0, salt_bits: int = 0):
     """Aggregate pre-packed single-occurrence rows (the sort-lean path).
 
     ``packed`` = ``pos << len_bits | length`` per live row (all-ones for
@@ -343,17 +341,12 @@ def from_packed_rows(key_hi: jax.Array, key_lo: jax.Array, packed: jax.Array,
     lane-major kernel layout, or the XLA backend's per-byte streams):
     stability then guarantees each segment's head row is the earliest
     input row = the smallest position — first occurrence for free.  The
-    round-4 sortbench measured the comparator-width cut at ~40% of the
+    round-4 chip notes measured the comparator-width cut at ~40% of the
     sort's compute (173.8 -> 143.2 ms on 16.8M rows, stability +1.2%)
     where the stream sort is the single-chip floor; sort3 remains for
     slot-major streams, which are NOT position-ordered.
 
-    ``sort_mode='segmin'`` also sorts two keys but recovers first
-    occurrence as a segmented running-min of ``packed`` (no input-order
-    precondition).  Bit-identical; REFUSED on TPU — its stream-sized
-    associative_scan wedges the chip (round-4 chip notes).
-
-    With ``rescue_slots = R > 0`` (sort3/stable2), also returns the first
+    With ``rescue_slots = R > 0``, also returns the first
     R ``packed`` values of the POISON segment — overlong-end markers
     (``pos << len_bits`` with zero length bits) carrying the reserved key
     (sent, sent-1), which sorts immediately before the dead-filler
@@ -363,29 +356,17 @@ def from_packed_rows(key_hi: jax.Array, key_lo: jax.Array, packed: jax.Array,
     these positions; riding the aggregation sort makes the extraction
     ~free (one log-n binary search plus an R-row slice), where any
     standalone compaction would cost a second stream-sized sort or
-    scatter.  Returns ``(table, rescue_packed)`` then; segmin cannot
-    order the poison segment (packed rides as payload in arbitrary
-    order), so that combination is rejected.
-
-    ``sort_impl`` picks the sort IMPLEMENTATION behind ``sort_mode``
-    (``Config.sort_impl``): ``'xla'`` is ``jax.lax.sort``; ``'radix'`` /
-    ``'radix_partition'`` route the stream through the Pallas radix
-    partition (:func:`mapreduce_tpu.ops.pallas.radix.radix_sort3`), whose
-    tie-by-``packed`` contract is bit-identical to sort3 outright and to
-    stable2 under its position-ordered-input precondition — so ONE radix
-    implementation serves both modes, poison-segment rescue extraction
-    included.  segmin is xla-only.
+    scatter.  Returns ``(table, rescue_packed)`` then.
 
     With ``salt_bits`` = B > 0 (``Config.combiner='salt'``, ISSUE 11: the
     pathological-single-key-stream tier below the hot-key cache), the low
     B position bits are XORed into ``key_lo`` BEFORE the sort — one
-    scorching key spreads over 2**B segments, defeating the measured ~4x
-    radix hot-key slab amplification — and the built table is de-salted
-    (the XOR is recoverable: every row of a salted segment shares
-    ``pos & (2**B - 1)``, so the kept head row's position undoes it) and
-    re-reduced through the generic build, coalescing the <= 2**B salted
-    entries per original key with exact counts and the true minimum
-    first occurrence.  Reserved-key rows (``key_hi == sent``: filler and
+    scorching key spreads over 2**B segments — and the built table is
+    de-salted (the XOR is recoverable: every row of a salted segment
+    shares ``pos & (2**B - 1)``, so the kept head row's position undoes
+    it) and re-reduced through the generic build, coalescing the <= 2**B
+    salted entries per original key with exact counts and the true
+    minimum first occurrence.  Reserved-key rows (``key_hi == sent``: filler and
     poison) are never salted, so the poison-segment rescue extraction is
     untouched.  Exactness envelope, both legs documented: (1) two
     DISTINCT hash keys that differ only by a legal salt XOR would
@@ -398,36 +379,15 @@ def from_packed_rows(key_hi: jax.Array, key_lo: jax.Array, packed: jax.Array,
     count — can differ from the unsalted build's (occurrence
     conservation still holds exactly through ``dropped_count``; this is
     the cross-table-merge dropped-accounting caveat the streamed paths
-    already document, not silent loss).  segmin is refused (its payload
-    scan keeps no per-salt-segment order to de-salt from).
+    already document, not silent loss).
 
     Matches :func:`_build` output bit-for-bit under its preconditions (every
     live row has count 1, one shared pos_hi).
     """
-    if sort_mode not in ("sort3", "stable2", "segmin"):
+    if sort_mode not in ("sort3", "stable2"):
         raise ValueError(f"unknown sort_mode {sort_mode!r}")
-    if salt_bits and sort_mode == "segmin":
-        raise ValueError("salt_bits requires sort_mode='sort3' or 'stable2'")
     if not 0 <= salt_bits <= 6:
         raise ValueError(f"salt_bits must be in [0, 6], got {salt_bits}")
-    if sort_impl not in ("xla", "radix", "radix_partition"):
-        raise ValueError(f"unknown sort_impl {sort_impl!r}")
-    if sort_impl != "xla" and sort_mode == "segmin":
-        raise ValueError("sort_impl='radix'/'radix_partition' requires "
-                         "sort_mode='sort3' or 'stable2' (segmin keeps "
-                         "packed as an unordered payload)")
-    if rescue_slots and sort_mode == "segmin":
-        raise ValueError("rescue_slots requires sort_mode='sort3' or "
-                         "'stable2' (poison extraction needs the poison "
-                         "segment position-ordered)")
-    if sort_mode == "segmin":
-        from mapreduce_tpu.config import (PlatformRefusedError,
-                                          SEGMIN_TPU_ERROR, segmin_allowed)
-
-        # Refuse the measured chip-wedge at trace time (the CPU A/B stays
-        # alive); config.segmin_allowed owns the deliberate override.
-        if jax.default_backend() == "tpu" and not segmin_allowed():
-            raise PlatformRefusedError(SEGMIN_TPU_ERROR)
     sent = jnp.uint32(constants.SENTINEL_KEY)
     inf = jnp.uint32(constants.POS_INF)
     n = key_hi.shape[0]
@@ -443,50 +403,17 @@ def from_packed_rows(key_hi: jax.Array, key_lo: jax.Array, packed: jax.Array,
         key_lo = jnp.where(key_hi != sent,
                            key_lo ^ ((packed >> len_bits) & smask), key_lo)
 
-    if sort_mode == "segmin":
-        key_hi, key_lo, packed = jax.lax.sort(
-            (key_hi, key_lo, packed), num_keys=2)
-        boundary, rank = _segment_boundaries(key_hi, key_lo)
-
-        def _min_combine(x, y):
-            # y is the later element; a boundary row restarts its segment.
-            xb, xv = x
-            yb, yv = y
-            return xb | yb, jnp.where(yb, yv, jnp.minimum(xv, yv))
-
-        _, run_min = jax.lax.associative_scan(_min_combine, (boundary, packed))
-    elif sort_impl != "xla":
-        # Radix path (Config.sort_impl): bit-identical to BOTH branches
-        # below — ties resolve by `packed`, which is sort3's third
-        # comparator key outright and, under stable2's position-ordered
-        # input, exactly the tie order stability delivers.  Adversarial
-        # bucket skew falls back to the XLA sort inside radix_sort3.
-        from mapreduce_tpu.ops.pallas import radix as radix_ops
-
-        # radix_geometry (ISSUE 12): an explicit (bits, block_rows,
-        # slab_slack) candidate from Config.geometry; None keeps the
-        # wrapper's call-time default resolution (the module-global
-        # geometry override tests rely on).
-        r_bits, r_rows, r_slack = radix_geometry or (None, None, None)
-        key_hi, key_lo, packed = radix_ops.radix_sort3(
-            key_hi, key_lo, packed, impl=sort_impl, bits=r_bits,
-            block_rows=r_rows, slab_slack=r_slack)
-        _, rank = _segment_boundaries(key_hi, key_lo)
-        run_min = None
-    elif sort_mode == "stable2":
+    if sort_mode == "stable2":
         # Stable two-key sort, packed as PAYLOAD: ties keep input order, so
         # with position-ordered input each segment's head row carries the
         # smallest position — the same first-occurrence invariant sort3
         # buys with a third comparator key.
         key_hi, key_lo, packed = jax.lax.sort(
             (key_hi, key_lo, packed), num_keys=2, is_stable=True)
-        _, rank = _segment_boundaries(key_hi, key_lo)
-        run_min = None
     else:
         key_hi, key_lo, packed = jax.lax.sort(
             (key_hi, key_lo, packed), num_keys=3)
-        _, rank = _segment_boundaries(key_hi, key_lo)
-        run_min = None
+    _, rank = _segment_boundaries(key_hi, key_lo)
 
     # Segment j occupies rows [head[j], head[j+1]) in sorted order.
     head = _segment_heads(rank, capacity)
@@ -494,13 +421,7 @@ def from_packed_rows(key_hi: jax.Array, key_lo: jax.Array, packed: jax.Array,
     count_u = (head[1:] - head[:capacity]).astype(jnp.uint32)
 
     key_hi_u, key_lo_u = key_hi[fi], key_lo[fi]
-    if run_min is None:
-        packed_u = packed[fi]  # sorted third key: head row IS min packed
-    else:
-        # The running min lands on each segment's LAST row (inclusive scan
-        # restarting at boundaries).
-        tail = jnp.minimum(jnp.maximum(head[1:], 1) - 1, n - 1)
-        packed_u = run_min[tail]
+    packed_u = packed[fi]  # each segment's head row holds its min packed
     occupied = (head[:capacity] < n) & (count_u > 0) \
         & ((key_hi_u != sent) | (key_lo_u < sent - jnp.uint32(1)))
 
@@ -558,8 +479,7 @@ def from_packed_rows(key_hi: jax.Array, key_lo: jax.Array, packed: jax.Array,
 def _from_stream_packed(stream: TokenStream, capacity: int,
                         pos_hi: jax.Array | int,
                         sort_mode: str = "sort3", rescue_slots: int = 0,
-                        sort_impl: str = "xla", salt_bits: int = 0,
-                        radix_geometry: tuple | None = None):
+                        salt_bits: int = 0):
     """Packed fast path for token streams: see :func:`from_packed_rows`."""
     # Packed-plane-carrying streams (the pallas kernel's PackedTokenStream)
     # feed their raw plane straight into the sort — repacking from
@@ -576,16 +496,14 @@ def _from_stream_packed(stream: TokenStream, capacity: int,
     return from_packed_rows(stream.key_hi, stream.key_lo, packed, total,
                             capacity, pos_hi, len_bits=6,
                             sort_mode=sort_mode, rescue_slots=rescue_slots,
-                            sort_impl=sort_impl, salt_bits=salt_bits,
-                            radix_geometry=radix_geometry)
+                            salt_bits=salt_bits)
 
 
 def from_stream(stream: TokenStream, capacity: int, pos_hi: jax.Array | int = 0,
                 max_token_bytes: int | None = None,
                 max_pos: int | None = None,
                 sort_mode: str = "sort3", rescue_slots: int = 0,
-                sort_impl: str = "xla", salt_bits: int = 0,
-                radix_geometry: tuple | None = None):
+                salt_bits: int = 0):
     """Aggregate a per-byte :class:`TokenStream` into a fresh table.
 
     ``pos_hi`` identifies the source buffer (e.g. ``step * n_devices +
@@ -598,28 +516,19 @@ def from_stream(stream: TokenStream, capacity: int, pos_hi: jax.Array | int = 0,
     the generic build; results are identical.  ``sort_mode`` picks that
     path's sort strategy (:func:`from_packed_rows`); ``rescue_slots`` (fast
     path only — the generic build has no poison rows to extract) makes the
-    return ``(table, rescue_packed)``.  ``sort_impl`` picks the fast
-    path's sort implementation (:func:`from_packed_rows`); the generic
-    7-array build below keeps ``lax.sort`` — the radix seam covers the
-    packed stream, which is the measured single-chip floor.  ``salt_bits``
-    (fast path only, ``Config.combiner='salt'``) spreads hot keys over
-    salted sort segments with an exact de-salting re-reduce
-    (:func:`from_packed_rows`).  ``radix_geometry`` (ISSUE 12) is an
-    explicit (bits, block_rows, slab_slack) candidate for the radix
-    implementations; None keeps the module defaults.
+    return ``(table, rescue_packed)``.  ``salt_bits`` (fast path only,
+    ``Config.combiner='salt'``) spreads hot keys over salted sort segments
+    with an exact de-salting re-reduce (:func:`from_packed_rows`).
     """
     if (max_token_bytes is not None and max_token_bytes <= 63
             and max_pos is not None and max_pos <= (1 << 26)):
         return _from_stream_packed(stream, capacity, pos_hi, sort_mode,
-                                   rescue_slots, sort_impl, salt_bits,
-                                   radix_geometry)
+                                   rescue_slots, salt_bits)
     if rescue_slots:
         raise ValueError("rescue_slots requires the packed fast path "
                          "(bounded max_token_bytes/max_pos)")
     if salt_bits:
-        raise ValueError("salt_bits applies to the packed fast path only "
-                         "(the generic 7-array build has no slab "
-                         "amplification to de-skew)")
+        raise ValueError("salt_bits applies to the packed fast path only")
     n = stream.key_hi.shape[0]
     ph = jnp.full((n,), jnp.asarray(pos_hi, dtype=jnp.uint32))
     ph = jnp.where(stream.count > 0, ph, jnp.uint32(constants.POS_INF))

@@ -346,8 +346,7 @@ def _config_summary(config: Config) -> dict:
     ``faults.next_degrade`` consumes exactly this shape)."""
     return {"geometry": config.geometry_label,
             "combiner": config.resolved_combiner,
-            "map_impl": config.map_impl,
-            "sort_impl": config.sort_impl}
+            "map_impl": config.map_impl}
 
 
 def _apply_degrade(config: Config, field: str, value: str) -> Config:
@@ -365,7 +364,7 @@ def _apply_degrade(config: Config, field: str, value: str) -> Config:
 def _job_with_config(job, config: Config):
     """Shallow-rebind a job's Config for a degradation-ladder step.  The
     ladder moves only knobs that leave state SHAPES untouched (geometry/
-    combiner/map_impl/sort_impl swap kernels, not pytrees — each shipped
+    combiner/map_impl swap kernels, not pytrees — each shipped
     with a bit-identity suite), so a copied job with the degraded config
     drives the same state through cheaper programs.  Composed jobs
     (sketch wrappers) rebind their base job too."""

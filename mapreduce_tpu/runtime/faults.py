@@ -70,7 +70,6 @@ DEGRADATION_LADDER = (
     ("revert-geometry", "geometry", "default"),
     ("combiner-off", "combiner", "off"),
     ("map-split", "map_impl", "split"),
-    ("sort-xla", "sort_impl", "xla"),
 )
 
 
@@ -338,7 +337,7 @@ class FailurePolicy:
 
 def next_degrade(current: dict) -> Optional[tuple[str, str, str]]:
     """The first :data:`DEGRADATION_LADDER` step still applicable to a
-    config summary ``{geometry, combiner, map_impl, sort_impl}`` (label
+    config summary ``{geometry, combiner, map_impl}`` (label
     values, e.g. ``Config.geometry_label`` for geometry), or None when
     the ladder is exhausted.  Returns ``(step_name, field, degraded_value)``.
     Jax-free on purpose: ``tools/chaos.py`` walks ladders from fixture

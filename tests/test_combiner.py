@@ -201,7 +201,7 @@ def test_salt_refusals():
         table_ops.from_packed_rows(
             jnp.zeros(8, jnp.uint32), jnp.zeros(8, jnp.uint32),
             jnp.zeros(8, jnp.uint32), jnp.uint32(0), 4, 0,
-            sort_mode="segmin", salt_bits=2)
+            salt_bits=7)  # past the 6 low position bits a row can spare
     with pytest.raises(ValueError, match="salt_bits"):
         table_ops.from_stream(
             tok_ops.TokenStream(*[jnp.zeros(8, jnp.uint32)] * 5), 4,
@@ -234,10 +234,11 @@ def test_auto_resolution_from_ledger():
 def test_config_surface():
     with pytest.raises(ValueError, match="combiner"):
         Config(combiner="always")
-    with pytest.raises(ValueError, match="salt"):
-        # Fail at construction, not mid-trace (the segmin payload scan
-        # has no per-segment order to de-salt from).
-        Config(combiner="salt", sort_mode="segmin")
+    # Salting rides both sort modes: each keeps a segment's rows in
+    # position order, which the de-salt reads.
+    for mode in ("sort3", "stable2"):
+        assert Config(combiner="salt", sort_mode=mode).resolved_salt_bits \
+            == 3
     with pytest.raises(ValueError, match="combiner_slots"):
         Config(combiner="hot-cache", combiner_slots=12)
     with pytest.raises(ValueError, match="combiner_slots"):

@@ -70,16 +70,12 @@ def test_defaults_match_measured_decisions():
     (round-4 chip notes, "the full suite"): 32 MB chunks beat both 1 MB
     (dispatch-bound) and 64 MB (sort superlinear + HBM pressure); slot
     compaction default-on at 88 (+25%); merge_every=1 (batching measured a
-    loss on top of compaction); sort3 (segmin wedges the chip).  A default
+    loss on top of compaction); stable2 (round-5 on-chip A/B).  A default
     drifting from the measured winner should fail loudly here (round-4 review
     weak #2: "production defaults ignore the round's own measurements")."""
     cfg = Config()
     assert cfg.chunk_bytes == 1 << 25  # 32 MB
     assert cfg.sort_mode == "stable2"  # round-5 on-chip A/B: +5.9% zipf
-    # Round-6 pricing note (round-4/5 chip notes): the radix partition loses 2-3x
-    # from measured rates — xla stays default until a live window says
-    # otherwise.
-    assert cfg.sort_impl == "xla"
     assert cfg.resolved_compact_slots == 128  # lane-major 384-byte windows
     assert cfg.resolved_block_rows == 384
     assert cfg.merge_every == 1
@@ -92,28 +88,7 @@ def test_defaults_match_measured_decisions():
     assert args.chunk_bytes == cfg.chunk_bytes
     assert args.merge_every == cfg.merge_every
     assert args.sort_mode == cfg.sort_mode
-    assert args.sort_impl == cfg.sort_impl
     assert args.compact_slots is None  # auto -> resolved_compact_slots
-
-
-def test_segmin_refused_on_tpu(monkeypatch):
-    """The segmin TPU wedge guard (round-4 review weak #3): tracing the packed
-    aggregation with sort_mode='segmin' while the default backend is TPU
-    must refuse, unless MAPREDUCE_ALLOW_SEGMIN opts in deliberately."""
-    import jax.numpy as jnp
-
-    from mapreduce_tpu.ops import table as table_ops
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.delenv("MAPREDUCE_ALLOW_SEGMIN", raising=False)
-    k = jnp.zeros((8,), jnp.uint32)
-    p = jnp.full((8,), 0xFFFFFFFF, dtype=jnp.uint32)
-    with pytest.raises(ValueError, match="segmin.*disabled|disabled.*segmin"):
-        table_ops.from_packed_rows(k, k, p, jnp.uint32(0), 4, 0,
-                                   sort_mode="segmin")
-    monkeypatch.setenv("MAPREDUCE_ALLOW_SEGMIN", "1")
-    table_ops.from_packed_rows(k, k, p, jnp.uint32(0), 4, 0,
-                               sort_mode="segmin")  # override path stays alive
 
 
 def test_inflight_groups_validation():

@@ -189,9 +189,10 @@ def test_production_kernel_plans_certified():
     assert findings  # every shipped geometry reports
     assert not [f for f in findings if f.severity == acore.ERROR], \
         "\n".join(f.format() for f in findings)
-    # All three kernel families covered.
+    # Every tokenize variant covered: compact, pair and fused.
     msgs = " ".join(f.message for f in findings)
-    assert "_tokenize_kernel" in msgs and "_partition_kernel" in msgs
+    assert "_tokenize_kernel" in msgs and "slots=pair" in msgs
+    assert "fused" in msgs
     assert "lane-major" in msgs  # the stable2 geometry
 
 

@@ -140,12 +140,12 @@ def test_plan_spec_roundtrip_and_determinism():
 @pytest.mark.smoke
 def test_degradation_ladder_walks():
     full = {"geometry": "tall512", "combiner": "hot-cache",
-            "map_impl": "fused", "sort_impl": "radix"}
+            "map_impl": "fused"}
     assert faults.ladder_walk(full) == [
-        "revert-geometry", "combiner-off", "map-split", "sort-xla"]
+        "revert-geometry", "combiner-off", "map-split"]
     assert faults.next_degrade(
-        {"geometry": "default", "combiner": "off", "map_impl": "split",
-         "sort_impl": "xla"}) is None
+        {"geometry": "default", "combiner": "off",
+         "map_impl": "split"}) is None
 
 
 def test_config_fault_surface():

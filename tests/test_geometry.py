@@ -34,8 +34,7 @@ def test_default_geometry_reproduces_shipped_plans():
     are the pre-refactor hand-maintained production_plans list's)."""
     expected = [(508416, 12, 67108864), (352768, 12, 67108864),
                 (475648, 8, None), (729600, 12, 67108864),
-                (860672, 12, 67108864), (631296, 8, None),
-                (3932160, 36, None), (3932160, 132, None)]
+                (860672, 12, 67108864), (631296, 8, None)]
     plans = meta.production_plans()
     got = [(p.vmem_bytes, p.smem_bytes, p.vmem_limit_bytes) for p in plans]
     assert got == expected, got
@@ -43,12 +42,13 @@ def test_default_geometry_reproduces_shipped_plans():
     assert [p.as_dict() for p in plans] == \
         [p.as_dict() for p in meta.geometry_plans(DEFAULT_GEOMETRY)]
     # The kernel wrappers delegate to the same constructor.
-    from mapreduce_tpu.ops.pallas import radix, tokenize
+    from mapreduce_tpu.ops.pallas import tokenize
 
     assert tokenize.vmem_plan(block_rows=384, compact_slots=128,
                               lane_major=True).as_dict() == \
         plans[0].as_dict()
-    assert radix.vmem_plan().as_dict() == plans[6].as_dict()
+    assert tokenize.vmem_plan(block_rows=256).as_dict() == \
+        plans[2].as_dict()
 
 
 def test_enumerator_candidates_all_pass_vmem_budget():
@@ -68,9 +68,9 @@ def test_enumerator_candidates_all_pass_vmem_budget():
 
 def test_known_overflow_candidate_rejected():
     """A tile-legal but over-budget geometry is rejected by the
-    certifier, not the dataclass: radix B=32 slabs at a 2048-row block
-    blow Mosaic's 16 MB default stack budget."""
-    bad = Geometry(radix_bits=5, radix_block_rows=2048)
+    certifier, not the dataclass: 16384-row pair windows blow Mosaic's
+    16 MB default stack budget, which the pair path does not override."""
+    bad = Geometry(pair_block_rows=16384)
     errs = geom_mod.certify(bad)
     assert errs and any("16 MiB budget" in e for e in errs), errs
     assert all(c.geometry != bad for c in geom_mod.enumerate_candidates())
@@ -97,9 +97,6 @@ def test_cost_ranking_matches_pr11_hand_arithmetic():
     from mapreduce_tpu.analysis import costmodel
 
     assert costmodel.stable2_sort_rows is geom_mod.stable2_sort_rows
-    # Radix slab write amplification derives the round-6 slack factor
-    # from the candidate, not a quote.
-    assert geom_mod.radix_slab_write_amplification(DEFAULT_GEOMETRY) == 4.0
 
 
 # -- Config surface ----------------------------------------------------------
@@ -127,15 +124,13 @@ def test_config_geometry_validation_and_resolution():
     base = Config()
     assert base.resolved_pair_block_rows is None
     assert base.resolved_aux_rows is None
-    assert base.resolved_radix_geometry is None
     assert Config(sort_mode="sort3").resolved_block_rows is None
     # Non-default fields thread through the resolvers.
-    g = Geometry(pair_block_rows=384, aux_rows=128, radix_bits=4,
+    g = Geometry(pair_block_rows=384, aux_rows=128,
                  sort3_block_rows=384, sort3_slots=128)
     cg = Config(geometry=g)
     assert cg.resolved_pair_block_rows == 384
     assert cg.resolved_aux_rows == 128
-    assert cg.resolved_radix_geometry == (4, 256, 4)
     assert Config(geometry=g, sort_mode="sort3").resolved_block_rows == 384
     assert Config(geometry=g,
                   sort_mode="sort3").resolved_compact_slots == 128
@@ -146,10 +141,9 @@ def test_config_geometry_validation_and_resolution():
     with pytest.raises(ValueError, match="compact_slots"):
         Config(geometry={"compact_slots": 120})
     for bad in (dict(block_rows=200), dict(aux_rows=64),
-                dict(sort3_slots=100), dict(radix_bits=6),
+                dict(sort3_slots=100), dict(pair_block_rows=200),
                 dict(combiner_slots=12), dict(block_rows=128),
-                dict(radix_bits=5, radix_block_rows=64,
-                     radix_slab_slack=1)):
+                dict(sort3_block_rows=100)):
         with pytest.raises(ValueError):
             Geometry(**bad)
     # Presets are themselves valid and include the documented pair arm.
@@ -369,7 +363,7 @@ def test_search_artifact_schema():
     for entry in art["shortlist"]:
         assert set(entry) == {"label", "axis", "sort_rows",
                               "sort_pass_bytes", "vmem_peak_bytes",
-                              "radix_amplification", "spill_risk",
+                              "spill_risk",
                               "geometry"}
         Geometry(**entry["geometry"])  # the spec round-trips
     json.dumps(art)

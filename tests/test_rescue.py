@@ -193,19 +193,10 @@ class TestRescueEnvelope:
 
 
 class TestRescueConfig:
-    def test_segmin_combination_rejected(self):
-        with pytest.raises(ValueError, match="sort3"):
-            Config(sort_mode="segmin", rescue_overlong=64)
-
-    def test_segmin_allowed_with_rescue_off(self):
-        Config(sort_mode="segmin", rescue_overlong=0)
-
     def test_default_auto_resolves_by_sort_mode(self):
-        # None (the default) = on under sort3, off under segmin — so
-        # constructing a segmin Config (CLI --sort-mode, BENCH_SORT_MODE)
-        # keeps working without touching the rescue knob.
+        # None (the default) = 1024 slots under either sort mode.
         assert Config().rescue_slots == 1024
-        assert Config(sort_mode="segmin").rescue_slots == 0
+        assert Config(sort_mode="sort3").rescue_slots == 1024
         assert Config(rescue_overlong=64).rescue_slots == 64
         assert Config(rescue_overlong=0).rescue_slots == 0
 

@@ -2,7 +2,7 @@
 
 The round-5 sort-floor attack (round-4 review next #1b): drop the third
 comparator key from the aggregation sort — ~40% of the sort's compute on
-the chip (round-4 chip notes sortbench) — by making the kernel emit its
+the chip (round-4 chip notes) — by making the kernel emit its
 compacted planes in global byte-position order (transposed lane-major
 blocks) so a STABLE two-key sort recovers first occurrence from tie order.
 
@@ -243,3 +243,120 @@ def test_gram_build_bit_identical_across_sort_modes(rng):
         b = wordcount.count_ngrams(corpus, 2, _cfg("stable2"))
     _assert_results_equal(a, b)
     assert any(len(w) > 140 for w in a.words)
+
+
+# -- the path the chip runs, against the plain reference ---------------------
+#
+# Every case below runs ONE program: the pallas backend in interpret mode,
+# stable2, production W, one Config and one buffer shape (each corpus is
+# padded with spaces to _REF_BYTES), so a worker compiles it once.  The
+# small primary rescue budget lets one program take both rescue tiers.
+
+_REF_BYTES = 1 << 16  # 128 lanes of 512 bytes: a lane spans > one window
+_REF_SEG = _REF_BYTES // 128
+_REF_CFG = Config(backend="pallas", sort_mode="stable2",
+                  chunk_bytes=_REF_BYTES, table_capacity=CAP,
+                  rescue_overlong=8, rescue_overlong_max=64,
+                  rescue_window=320)
+
+
+def _ref_zipf(rng):
+    return make_corpus(rng, n_words=9000, vocab=600)
+
+
+def _ref_uniform(rng):
+    words = [b"u%d" % i for i in range(1500)]
+    return b" ".join(words[i] for i in rng.integers(0, 1500, size=8000))
+
+
+def _ref_single_key(rng):
+    return b"hello " * 10000
+
+
+def _ref_dense_spill(rng):
+    # Density 0.5 fills a 384-byte window with 192 ends, past its 128
+    # slots: the chunk falls back to the exact pair path (sort3).
+    return make_corpus(rng, n_words=2000, vocab=100) + b" " \
+        + b"a b " * 4000
+
+
+def _ref_late_first_occurrence(rng):
+    # First occurrences deep in the high lanes, in reverse name order, then
+    # repetitions spread over later lanes: a wrong tie order would report
+    # a later occurrence's position and misorder the words.
+    words = [b"zz%d" % i for i in range(40)]
+    filler = b"filler " * ((_REF_BYTES * 5 // 8) // 7)
+    head = b" ".join(reversed(words))
+    bulk = b" ".join(words[i] for i in rng.integers(0, 40, size=3000))
+    return filler + head + b" " + bulk
+
+
+def _ref_overlong_in_lane(rng):
+    body = make_corpus(rng, n_words=3000, vocab=200)
+    cut = len(body) // 2
+    cut = body.index(b" ", cut) + 1
+    return body[:cut] + b"v" * 40 + b" " + b"w" * 150 + b" " \
+        + body[cut:] + b" " + b"v" * 40
+
+
+def _ref_overlong_lane_seam(rng):
+    buf = bytearray(make_corpus(rng, n_words=6000, vocab=300)
+                    .ljust(_REF_BYTES - 1024))
+    for seam in (_REF_SEG, 37 * _REF_SEG):
+        # A 60-byte token straddling the lane seam, separators around it.
+        buf[seam - 31:seam + 31] = b" " + b"s" * 60 + b" "
+    return bytes(buf)
+
+
+def _ref_overlong_second_tier(rng):
+    # 24 overlong occurrences (> the 8-slot primary budget): counted
+    # exactly only by the 64-slot second tier.
+    urls = [b"http://example.org/%d/" % i + b"p" * (30 + 9 * i)
+            for i in range(12)]
+    words = [b"the", b"of", b"and", b"a"] * 1500 + urls * 2
+    order = rng.permutation(len(words))
+    return b" ".join(words[i] for i in order)
+
+
+def _ref_multibyte_utf8(rng):
+    vocab = ["naïve", "café", "東京", "Привет", "😀", "ß", "مرحبا",
+             "a😀b", "ﬁ", "κόσμε"]
+    words = [vocab[i].encode() for i in rng.integers(0, len(vocab), 6000)]
+    return b" ".join(words)
+
+
+def _ref_nul_bearing(rng):
+    # NUL is a separator; other control and high bytes are token bytes.
+    vocab = [b"ab", b"\x01cd", b"\x7f\xff", b"e\x80f", b"gh"]
+    seps = [b"\x00", b" ", b"\x00\x00", b"\t\x00"]
+    parts = []
+    for i in rng.integers(0, len(vocab), 6000):
+        parts.append(vocab[i] + seps[int(i) % len(seps)])
+    return b"\x00" + b"".join(parts)
+
+
+_REF_CASES = {
+    "zipf": _ref_zipf,
+    "uniform": _ref_uniform,
+    "single_key": _ref_single_key,
+    "dense_spill": _ref_dense_spill,
+    "late_first_occurrence": _ref_late_first_occurrence,
+    "overlong_in_lane": _ref_overlong_in_lane,
+    "overlong_lane_seam": _ref_overlong_lane_seam,
+    "overlong_second_tier": _ref_overlong_second_tier,
+    "multibyte_utf8": _ref_multibyte_utf8,
+    "nul_bearing": _ref_nul_bearing,
+}
+
+
+@pytest.mark.parametrize("case", list(_REF_CASES))
+def test_stable2_matches_reference(case):
+    data = _REF_CASES[case](np.random.default_rng(29))
+    assert len(data) <= _REF_BYTES, len(data)
+    data = data.ljust(_REF_BYTES)
+    with _interpret():
+        r = wordcount.count_words(data, _REF_CFG)
+    expected = oracle.word_counts(data)
+    assert list(zip(r.words, r.counts)) == list(expected.items())
+    assert r.total == oracle.total_count(data)
+    assert r.dropped_count == 0

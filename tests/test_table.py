@@ -253,35 +253,6 @@ def _random_packed_rows(rng, n, n_keys):
 
 
 @pytest.mark.slow
-def test_segmin_sort_mode_bit_identical(rng):
-    """sort_mode='segmin' (2-key sort + segmented running-min) must equal
-    sort_mode='sort3' leaf-for-leaf, including first-occurrence positions,
-    spill accounting under capacity pressure, and sentinel handling."""
-    for n, n_keys, cap in ((1 << 12, 200, 256), (1 << 12, 200, 64),
-                           (1 << 10, 5, 16), (1 << 10, 1000, 1 << 11)):
-        khi, klo, packed, n_live = _random_packed_rows(rng, n, n_keys)
-        total = jnp.uint32(n_live)
-        a = tbl.from_packed_rows(khi, klo, packed, total, cap, pos_hi=3,
-                                 sort_mode="sort3")
-        b = tbl.from_packed_rows(khi, klo, packed, total, cap, pos_hi=3,
-                                 sort_mode="segmin")
-        for la, lb, name in zip(a, b, a._fields):
-            np.testing.assert_array_equal(np.asarray(la), np.asarray(lb),
-                                          err_msg=f"{name} n={n} cap={cap}")
-
-
-@pytest.mark.slow
-def test_segmin_end_to_end_equals_sort3(small_corpus):
-    """The full pallas-path pipeline under sort_mode='segmin' produces the
-    identical result object (interpret mode on CPU)."""
-    base = dict(chunk_bytes=1 << 14, table_capacity=1 << 10, backend="pallas")
-    r3 = wordcount.count_words(small_corpus, Config(**base, sort_mode="sort3"))
-    rm = wordcount.count_words(small_corpus, Config(**base, sort_mode="segmin"))
-    assert r3.as_dict() == rm.as_dict()
-    assert r3.words == rm.words and r3.counts == rm.counts
-
-
-@pytest.mark.slow
 def test_kmv_distinct_under_capacity_pressure(rng):
     """round-2 review #8: under table spill, ``distinct`` is the table's free
     KMV estimate (the full table's kept keys are the bottom-capacity key

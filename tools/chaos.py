@@ -174,16 +174,16 @@ def selftest() -> int:
 
     # --- ladder walks from fixture dicts, by hand.
     full = {"geometry": "tall512", "combiner": "hot-cache",
-            "map_impl": "fused", "sort_impl": "radix"}
+            "map_impl": "fused"}
     assert fm.ladder_walk(full) == ["revert-geometry", "combiner-off",
-                                    "map-split", "sort-xla"]
+                                    "map-split"]
     assert fm.next_degrade(full) == ("revert-geometry", "geometry",
                                      "default")
     part = {"geometry": "default", "combiner": "off",
-            "map_impl": "fused", "sort_impl": "xla"}
+            "map_impl": "fused"}
     assert fm.ladder_walk(part) == ["map-split"]
     done = {"geometry": "default", "combiner": "off",
-            "map_impl": "split", "sort_impl": "xla"}
+            "map_impl": "split"}
     assert fm.next_degrade(done) is None and fm.ladder_walk(done) == []
 
     # --- plan determinism: same seed -> same firing set; rate=0 silent;
@@ -266,7 +266,7 @@ def selftest() -> int:
         assert rewild.decide(seam, index) == fcls
 
     print(f"chaos selftest ok (backoff 0.05->5.0 capped x{len(want)}, "
-          f"jitter bounded deterministic, 4-step ladder walk, "
+          f"jitter bounded deterministic, 3-step ladder walk, "
           f"plan determinism {len(a)} firings @5%, spec round-trip, "
           f"fixture11 replay {len(seq)} faults, "
           f"own-ledger replay {len(wild.fired)} faults)")

@@ -6,8 +6,8 @@ Three stages, each cheaper than the next is allowed to be:
 1. **Enumerate + certify + rank** (default; jax-free): walk the candidate
    lattice in ``mapreduce_tpu/analysis/geometry.py``, drop anything the
    static vmem certifier rejects, price the survivors with the hbm-cost
-   model's own arithmetic (stable2 sort rows / radix slab amplification
-   re-derived from each CANDIDATE), and print the ranked shortlist as one
+   model's own arithmetic (stable2 sort rows re-derived from each
+   CANDIDATE), and print the ranked shortlist as one
    JSON artifact — no jax, no device.
 2. ``--gate``: run the full graphcheck pipeline (reducer-algebra,
    overflow, host-sync, sharding, **vmem-budget, kernel-race,
@@ -145,9 +145,8 @@ def run_probe(args, geom_mod) -> int:
                  if c.axis == "default"] + cands
 
     # Drop candidates whose varied axis is INERT in the probe config
-    # (fused/stable2/xla-sort/combiner-off): a radix- or sort3-axis
-    # candidate resolves to the exact same program there, so probing it
-    # measures run-to-run noise and can crown a no-op knob the winner.
+    # (fused/stable2/combiner-off): a sort3-axis candidate resolves to the
+    # exact same program there, so probing it measures run-to-run noise and can crown a no-op knob the winner.
     # Resolved-value comparison, not axis names, so the filter can never
     # drift from what Config actually reads.  Logged, never silent
     # (the no-silent-caps rule).
@@ -156,7 +155,7 @@ def run_probe(args, geom_mod) -> int:
                      geometry=None if c.axis == "default" else c.geometry)
         return (cfg.resolved_block_rows, cfg.resolved_compact_slots,
                 cfg.resolved_pair_block_rows, cfg.resolved_aux_rows,
-                cfg.resolved_radix_geometry, cfg.resolved_combiner_slots)
+                cfg.resolved_combiner_slots)
 
     default_resolved = _probe_resolved(
         next(c for c in cands if c.axis == "default"))
@@ -258,8 +257,7 @@ def selftest() -> int:
     # criterion; the values are the pre-refactor hand-maintained list's).
     expected = [(508416, 12, 67108864), (352768, 12, 67108864),
                 (475648, 8, None), (729600, 12, 67108864),
-                (860672, 12, 67108864), (631296, 8, None),
-                (3932160, 36, None), (3932160, 132, None)]
+                (860672, 12, 67108864), (631296, 8, None)]
     plans = meta.production_plans()
     got = [(p.vmem_bytes, p.smem_bytes, p.vmem_limit_bytes) for p in plans]
     assert got == expected, f"production plans drifted: {got}"
@@ -272,11 +270,10 @@ def selftest() -> int:
     assert all(not g.certify(c.geometry) for c in cands)
     assert sum(c.axis == "default" for c in cands) == 1
 
-    # A known-overflow candidate is rejected: radix B=32 slabs at a
-    # 2048-row block are 3*32*256 double-buffered slab rows per grid step
-    # — past Mosaic's 16 MB default stack budget, which the partition
-    # kernel does not override.
-    bad = g.Geometry(radix_bits=5, radix_block_rows=2048)
+    # A known-overflow candidate is rejected: 16384-row pair windows are
+    # ~28 MiB of double-buffered blocks per grid step — past Mosaic's
+    # 16 MB default stack budget, which the pair path does not override.
+    bad = g.Geometry(pair_block_rows=16384)
     errs = g.certify(bad)
     assert errs and any("16 MiB budget" in e for e in errs), errs
     assert not any(c.geometry == bad for c in cands)
@@ -300,10 +297,6 @@ def selftest() -> int:
     assert art["default"]["sort_rows"] == 11206656
     assert len(art["shortlist"]) == 5
     json.dumps(art)  # the artifact is JSON-clean
-
-    # Radix slab amplification derives the round-6 slack factor from the
-    # candidate, not a quote: cap*B/block == slack when unclamped.
-    assert g.radix_slab_write_amplification(g.DEFAULT_GEOMETRY) == 4.0
 
     # The tuner's geometry knob (the second non-numeric knob): propose on
     # the device-bound-with-headroom fixture, revert on the spilling
@@ -376,7 +369,7 @@ def main(argv=None) -> int:
                     help="shortlist size (default 5)")
     ap.add_argument("--axis", default=None,
                     help="narrow the lattice to one axis family "
-                         "(block_rows, sort3, radix, ...)")
+                         "(block_rows, sort3, ...)")
     ap.add_argument("--gate", action="store_true",
                     help="run the graphcheck pipeline over the shortlist "
                          "(host tracing; no device)")

@@ -72,18 +72,6 @@ def main(argv=None) -> int:
         "stable2_lane_major": Config(backend="pallas", chunk_bytes=1 << 20,
                                      table_capacity=1 << 16,
                                      sort_mode="stable2"),
-        # The round-6 radix partition kernel (ops/pallas/radix.py): its
-        # Mosaic surface — SMEM (1, B) histogram blocks, 3*B+2 output
-        # refs, uint32 digit shifts — has never lowered on a real chip;
-        # smoking it here finds a lowering failure before a radix A/B
-        # spends chip time on it (the interpret suite validates
-        # semantics only).
-        "stable2_radix_partition": Config(backend="pallas",
-                                          chunk_bytes=1 << 20,
-                                          table_capacity=1 << 16,
-                                          sort_impl="radix_partition"),
-        "stable2_radix": Config(backend="pallas", chunk_bytes=1 << 20,
-                                table_capacity=1 << 16, sort_impl="radix"),
         # ISSUE 11 map-side combiner: the hot-key cache's Mosaic surface
         # — four revisited (8, 128) output refs, axis-0 sublane
         # reductions, masked one-hot selects — has never lowered on a

@@ -13,8 +13,7 @@ gather/scatter, pallas kernel, ...).
 Run on the chip:  python tools/opshare.py          (ambient backend)
 CPU sanity:       JAX_PLATFORMS=cpu python tools/opshare.py
 
-Env knobs: OPSHARE_CHUNK_MB (default 32), OPSHARE_SORT_MODE (sort3|segmin),
-OPSHARE_SORT_IMPL (xla|radix|radix_partition — the round-6 radix A/B),
+Env knobs: OPSHARE_CHUNK_MB (default 32), OPSHARE_SORT_MODE (sort3|stable2),
 OPSHARE_MERGE_EVERY (default 1), OPSHARE_STEPS (steps profiled, default 4).
 Prints a final JSON line {"sort_share": ..., "top": [...]} for machines.
 """
@@ -71,27 +70,16 @@ def main() -> int:
     from mapreduce_tpu.parallel.mapreduce import Engine
     from mapreduce_tpu.parallel.mesh import data_mesh
 
-    sort_mode = os.environ.get("OPSHARE_SORT_MODE", Config.sort_mode)
-    if sort_mode == "segmin" and jax.default_backend() == "tpu" \
-            and os.environ.get("OPSHARE_FORCE", "0") != "1":
-        # Measured 2026-07-31: the 16.8M-row segmented associative_scan
-        # hung the chip for >30 min (twice in sortbench, once in a full
-        # bench) — refusing beats burning the chip budget.
-        # OPSHARE_FORCE=1 overrides.
-        print(json.dumps({"skipped": "segmin on tpu: giant associative_scan "
-                                     "hangs the chip (round 4)"}))
-        return 0
     cfg = Config(chunk_bytes=chunk_mb << 20, table_capacity=1 << 18,
                  batch_unique_capacity=1 << 16,
-                 sort_mode=sort_mode,
-                 sort_impl=os.environ.get("OPSHARE_SORT_IMPL",
-                                          Config.sort_impl),
+                 sort_mode=os.environ.get("OPSHARE_SORT_MODE",
+                                          Config.sort_mode),
                  merge_every=int(os.environ.get("OPSHARE_MERGE_EVERY", "1")),
                  compact_slots=(int(os.environ["OPSHARE_COMPACT_SLOTS"])
                                 if "OPSHARE_COMPACT_SLOTS" in os.environ
                                 else None))
     print(f"backend={jax.default_backend()} chunk={chunk_mb}MB "
-          f"sort_mode={cfg.sort_mode} sort_impl={cfg.sort_impl} "
+          f"sort_mode={cfg.sort_mode} "
           f"merge_every={cfg.merge_every} steps={steps}", file=sys.stderr)
 
     rng = np.random.default_rng(3)
@@ -180,7 +168,7 @@ def main() -> int:
     print(json.dumps({
         "backend": jax.default_backend(),
         "chunk_mb": chunk_mb, "steps": steps,
-        "sort_mode": cfg.sort_mode, "sort_impl": cfg.sort_impl,
+        "sort_mode": cfg.sort_mode,
         "merge_every": cfg.merge_every,
         "compact_slots": cfg.compact_slots,
         "total_device_us": round(total, 0),
