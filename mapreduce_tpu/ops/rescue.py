@@ -17,8 +17,17 @@ window — with the XLA backend's own key definition
 (:func:`...ops.tokenize.last_token`), so bit-identical by construction —
 recovers each token's exact key/length/start, and a tiny table
 built from those rows merges into the chunk's batch table.  The whole pass
-sits under a ``lax.cond(overlong > 0)`` in the caller: corpora without
-overlong tokens (both bench generators, test.txt) never pay for it.
+sits under a ``lax.cond(overlong > 0)`` in the caller: chunks without
+overlong tokens (the Zipf bench corpus, test.txt) never pay for it, while
+URL-dense text pays on every chunk (the ``webby-wordcount`` benchmark
+text, ~16,000 URLs a 32 MiB chunk, takes the second tier on each).
+
+The windows are fetched as rows, not bytes: the front-padded chunk is
+viewed as 128-byte rows, each window is gathered as the few rows that
+cover it (one index a row: 4 for a 320-byte window), and a log-shifter of
+static slices brings its first byte to column 0.  A gather with one index
+a byte cost ~85 ms a 32 MiB chunk on a v5e at 32,768 slots of 320 bytes;
+the row fetch ~0.6 ms (PERF.md).
 
 Envelope, by construction rather than silence:
   * tokens longer than ``window - 1`` bytes cannot be verified complete in
@@ -40,6 +49,8 @@ from mapreduce_tpu import constants
 from mapreduce_tpu.ops import table as table_ops
 from mapreduce_tpu.ops import tokenize as tok_ops
 
+_ROW = 128  # bytes a row of the window fetch: the lane width
+
 
 def rescue_table(chunk: jax.Array, rescue_packed: jax.Array, w: int,
                  window: int, pos_hi: jax.Array | int
@@ -59,28 +70,22 @@ def rescue_table(chunk: jax.Array, rescue_packed: jax.Array, w: int,
         (w, window-1] are rescued; longer ones stay accounted).
       pos_hi: the chunk id, so first-occurrence order stays global.
 
+    Each slot's window, the ``window`` bytes that end at its poison
+    position, is fetched as whole 128-byte rows and shifted into place
+    (:func:`_windows`); the token ending at its last byte is then hashed.
+
     Returns:
       ``(table, rescued)``: a capacity-R table of the rescued tokens (their
       exact 64-bit keys, counts, first-occurrence positions and true
       lengths) and the uint32 number of occurrences rescued.
     """
-    n = int(chunk.shape[0])
     r = rescue_packed.shape[0]
     ones = jnp.uint32(0xFFFFFFFF)
     is_poison = (rescue_packed != ones) & \
         ((rescue_packed & jnp.uint32(63)) == 0)
     p = (rescue_packed >> 6).astype(jnp.int32)  # last byte of each run
 
-    # Window i = chunk[p_i - window + 1 .. p_i], read from a front-padded
-    # copy so early positions need no clamping (PAD is a separator, so the
-    # synthetic prefix can never extend a run).  Dead slots index past the
-    # end; clip-mode gather returns arbitrary in-range bytes that the
-    # is_poison mask discards.
-    padded = jnp.concatenate(
-        [jnp.full((window,), constants.PAD_BYTE, jnp.uint8), chunk])
-    idx = jnp.minimum(p[:, None] + 1 + jnp.arange(window, dtype=jnp.int32),
-                      jnp.int32(n + window - 1))
-    windows = jnp.take(padded, idx, axis=0)  # (R, window) uint8
+    windows = _windows(chunk, p, window)  # (R, window) uint8
 
     # The token ending at each window's last byte, hashed by the XLA
     # backend's own key definition (closed form: no scan to compile).
@@ -107,3 +112,41 @@ def rescue_table(chunk: jax.Array, rescue_packed: jax.Array, w: int,
     # Generic build (lengths exceed the 6-bit packed bound); R rows, so the
     # sort is noise.  Capacity R: at most R distinct keys, nothing can drop.
     return table_ops.from_stream(stream, r, pos_hi=pos_hi), rescued
+
+
+def _windows(chunk: jax.Array, p: jax.Array, window: int) -> jax.Array:
+    """uint8[R, window]: row i holds ``chunk[p_i - window + 1 .. p_i]``,
+    PAD where that reaches before the chunk's start.
+
+    The chunk, padded in front to a whole number of rows (at least
+    ``window`` PAD bytes: a separator, so the prefix never extends a run)
+    and at the end to a whole row, is viewed as ``(rows, 128)``.  Each
+    window starts ``start mod 128`` bytes into row ``start // 128`` and
+    spans at most ``k`` rows; those rows are gathered whole (one index a
+    row) and shifted left.  A slot whose rows run past the last is clamped
+    to it: that is a dead slot (all-ones filler) or bytes past the
+    window's end, which the shift drops.
+    """
+    n = int(chunk.shape[0])
+    r = p.shape[0]
+    front = -(-window // _ROW) * _ROW
+    total = -(-(front + n) // _ROW) * _ROW
+    rows = jnp.concatenate([
+        jnp.full((front,), constants.PAD_BYTE, jnp.uint8), chunk,
+        jnp.full((total - front - n,), constants.PAD_BYTE, jnp.uint8),
+    ]).reshape(-1, _ROW)
+    start = p + 1 + front - window  # each window's first byte in ``rows``
+    k = -(-(_ROW - 1 + window) // _ROW)
+    ridx = (start // _ROW)[:, None] + jnp.arange(k, dtype=jnp.int32)
+    got = jnp.take(rows, ridx, axis=0, mode="clip").reshape(r, k * _ROW)
+    # Shift each row left by start mod 128: 7 static slices, each taken
+    # or not per row by one bit of the shift; ``keep`` is what the smaller
+    # shifts still need.
+    shift = start % _ROW
+    d = _ROW // 2
+    while d:
+        keep = window + d - 1
+        got = jnp.where(((shift & d) != 0)[:, None], got[:, d:d + keep],
+                        got[:, :keep])
+        d //= 2
+    return got

@@ -220,3 +220,103 @@ class TestRescueConfig:
         rx = wc.count_words(text, _cfg("xla"))
         assert got.as_dict() == rx.as_dict()
         assert got.total == rx.total
+
+
+def _byte_gather(chunk, p, window):
+    """The window fetch with one index a byte (ops/rescue.py before the row
+    fetch): the front-padded chunk gathered at ``p + 1 + j``."""
+    import jax.numpy as jnp
+
+    from mapreduce_tpu import constants
+
+    n = chunk.shape[0]
+    padded = jnp.concatenate(
+        [jnp.full((window,), constants.PAD_BYTE, jnp.uint8), chunk])
+    idx = jnp.minimum(p[:, None] + 1 + jnp.arange(window, dtype=jnp.int32),
+                      jnp.int32(n + window - 1))
+    return jnp.take(padded, idx, axis=0)
+
+
+_W = 32  # the kernel's W: every poison marks a run longer than this
+
+
+def _fetch_case(n, window, seed):
+    """(chunk, rescue_packed) of one window-fetch case.
+
+    The chunk is runs of letters between single separators, run lengths
+    drawn from short words, ``w + 1``, ``window - 1``, ``window`` and
+    ``window + 7``.  The packed rows are poison rows at the end of every
+    run longer than ``w``, at every residue mod 4 and at 0, 1, 127, 128,
+    129, 255, 256 and 511 past a multiple of 512 (the row fetch reads
+    128-byte rows), at ``p < window`` and at ``p = n - 1``; then
+    real-token rows (nonzero length bits) and all-ones filler.
+    """
+    rng = np.random.default_rng(seed)
+    lengths = np.array([1, 3, 5, _W + 1, window - 1, window, window + 7])
+    buf = bytearray()
+    ends = []
+    while len(buf) < n:
+        ln = int(lengths[rng.integers(0, len(lengths))])
+        buf += bytes(rng.integers(97, 123, ln, dtype=np.uint8)) + b" "
+        ends.append(len(buf) - 2)
+    chunk = np.frombuffer(bytes(buf[:n]), np.uint8)
+    ps = {e for e in ends if e < n}
+    ps |= {base + r for base in range(0, n, 1536)
+           for r in (0, 1, 2, 3, 127, 128, 129, 255, 256, 511)}
+    ps |= {0, 1, 2, 3, window - 2, window - 1, window, n - 1}
+    ps = sorted(q for q in ps if 0 <= q < n)
+    poison = np.asarray(ps, np.uint32) << 6
+    real = (np.asarray(ps[:5], np.uint32) << 6) | np.uint32(7)
+    filler = np.full(9, 0xFFFFFFFF, np.uint32)
+    return chunk, np.concatenate([poison, real, filler])
+
+
+# (n, window): chunk lengths a multiple of the 128-byte row, and not a
+# multiple of 4 or of 128; windows from the smallest (w + 2) to the
+# largest (4096) that Config allows, and the benchmark's 320.
+_FETCH_CASES = {
+    "aligned_n_320": (8192, 320),
+    "ragged_n_320": (8192 + 131, 320),
+    "ragged_n_192": (6003, 192),
+    "smallest_window": (4099, _W + 2),
+    "largest_window": (3 * 4096 + 5, 4096),
+    "chunk_shorter_than_window": (101, 320),
+}
+
+
+class TestWindowFetch:
+    """The row fetch (``ops/rescue.py`` ``_windows``) gives, for every
+    slot the rescue keeps, the bytes of the one-index-a-byte gather, and
+    ``rescue_table`` built on it is the byte gather's, bit for bit."""
+
+    @pytest.mark.parametrize("case", sorted(_FETCH_CASES))
+    def test_windows_match_byte_gather(self, case):
+        import jax.numpy as jnp
+
+        from mapreduce_tpu.ops import rescue as rescue_ops
+
+        n, window = _FETCH_CASES[case]
+        chunk, packed = _fetch_case(n, window, seed=n)
+        p = jnp.asarray((packed >> 6).astype(np.int32))
+        got = np.asarray(rescue_ops._windows(jnp.asarray(chunk), p, window))
+        want = np.asarray(_byte_gather(jnp.asarray(chunk), p, window))
+        assert got.shape == want.shape == (packed.shape[0], window)
+        keep = (packed != 0xFFFFFFFF) & ((packed & 63) == 0)
+        np.testing.assert_array_equal(got[keep], want[keep])
+
+    @pytest.mark.parametrize("case", sorted(_FETCH_CASES))
+    def test_rescue_table_matches_byte_gather(self, case, monkeypatch):
+        import jax.numpy as jnp
+
+        from mapreduce_tpu.ops import rescue as rescue_ops
+
+        n, window = _FETCH_CASES[case]
+        chunk, packed = _fetch_case(n, window, seed=n + 1)
+        args = (jnp.asarray(chunk), jnp.asarray(packed), _W, window, 3)
+        got, got_n = rescue_ops.rescue_table(*args)
+        monkeypatch.setattr(rescue_ops, "_windows", _byte_gather)
+        want, want_n = rescue_ops.rescue_table(*args)
+        assert int(got_n) == int(want_n) > 0
+        for name, a, b in zip(got._fields, got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=name)
